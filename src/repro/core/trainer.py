@@ -77,6 +77,28 @@ class BoostConfig:
     seed: int = 0
 
 
+def _jit_hoisting_consts(fn):
+    """``jax.jit(fn)``, except that every array ``fn`` closes over
+    reaches the compiled program as an argument instead of being baked
+    into it as an HLO constant.  The level step closes over the engine's
+    base factors (a 2^20-row sketch factor alone is 1 GB) and one program
+    compiles per (level, #prev-leaves): embedded, each program would
+    hold its own copy of every factor in device memory."""
+    cache = {}
+
+    def call(*args):
+        flat, tree = jax.tree.flatten(args)
+        key = (tree, tuple((a.shape, a.dtype) for a in flat))
+        if key not in cache:
+            closed, out = jax.make_jaxpr(fn, return_shape=True)(*args)
+            cache[key] = (jax.jit(partial(jax.core.eval_jaxpr, closed.jaxpr)),
+                          closed.consts, jax.tree.structure(out))
+        run, consts, out_tree = cache[key]
+        return jax.tree.unflatten(out_tree, run(consts, *flat))
+
+    return call
+
+
 @dataclasses.dataclass
 class FitTrace:
     """Everything tests/benchmarks need to validate the paper's claims."""
@@ -110,7 +132,7 @@ class Booster:
         self.engine.bind(self)
         self.plans = self._build_plans()
         if self.engine.jittable:
-            self._level_step = jax.jit(self._level_step_impl)
+            self._level_step = _jit_hoisting_consts(self._level_step_impl)
             self._leaf_masks = jax.jit(self._leaf_masks_impl)
         else:                                # host-side caching engines hash
             self._level_step = self._level_step_impl   # concrete mask bytes

@@ -114,7 +114,9 @@ def descend_masks_level(
     vals = jnp.take(fm, jnp.maximum(local, 0), axis=1).T        # (K, n)
     cond = vals >= thr[:, None]                                  # (K, n)
     left = masks & (~mine[:, None] | ~cond)
-    right = masks & (~mine[:, None] | cond)
+    # a dead node owns no feature in any table, so the pass-through above
+    # would put its rows in both children: its right child is empty
+    right = masks & (feat >= 0)[:, None] & (~mine[:, None] | cond)
     return jnp.stack([left, right], axis=1).reshape(-1, masks.shape[-1])
 
 

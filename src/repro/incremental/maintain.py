@@ -58,7 +58,8 @@ from ..obs import get_registry, span
 from ..core.schema import Schema
 from ..core.sumprod import QueryCounter, SumProd, refresh_plan
 from ..distributed import spmd
-from ..serving.compile import CompiledEnsemble, compile_ensemble, stack_table_factor
+from ..serving.compile import (
+    CompiledEnsemble, compile_ensemble, contract_leaves, stack_table_factor)
 from .deltas import DynamicEdge, DynamicTable, TableDelta
 from .state import DynamicState, StateView
 
@@ -344,10 +345,8 @@ class MaintainedScorer:
         contraction as the compiled scorer.  Dead slots read (0, 0)."""
         if self.counter is not None:
             self.counter.bump(1)
-        counts = self._counts(group_by)
-        tot = (counts @ self.leaf_values).astype(jnp.float32)
-        cnt = jnp.sum(counts[:, :self.tree0_leaves], axis=1).astype(jnp.float32)
-        return tot, cnt
+        return contract_leaves(self._counts(group_by), self.leaf_values,
+                               self.tree0_leaves)
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         if group_by not in self._grouped:
@@ -360,12 +359,9 @@ class MaintainedScorer:
         evaluated through an eager message pass.  Returned arrays are
         capacity-shaped (live slots filled, dead slots 0) so they compare
         bit-for-bit against the maintained grouped output: the leaf
-        counts are integer-exact either way, and routing the final
-        contraction through the same-shape matvec removes the one
-        remaining float-reassociation freedom (XLA's gemv blocks rows
-        differently for different n, which would otherwise perturb a few
-        ulps).  A jitted ``compile_ensemble(...).score_grouped`` agrees
-        to allclose, not bitwise — its fused matvec reassociates."""
+        counts are integer-exact either way, and the final contraction
+        is the same per-row FMA chain (`contract_leaves`), so no
+        float-reassociation freedom remains."""
         with self.state.lock:
             eff = self.effective_schema()
             live = self.live_rows(group_by)
@@ -390,9 +386,7 @@ class MaintainedScorer:
         full = jnp.zeros(
             (capacity, counts.shape[1]), counts.dtype
         ).at[jnp.asarray(live, jnp.int32)].set(counts)
-        tot = (full @ fresh.leaf_values).astype(jnp.float32)
-        cnt = jnp.sum(full[:, :fresh.tree0_leaves], axis=1).astype(jnp.float32)
-        return tot, cnt
+        return contract_leaves(full, fresh.leaf_values, fresh.tree0_leaves)
 
     # ----------------------------------------------------------- snapshots --
     def snapshot(self, roots: Optional[Sequence[str]] = None,
@@ -504,9 +498,7 @@ class MaintainedScorer:
         counts = spmd.replicate(
             self._sp.node_factor(self._sem, self.factors, jt, jt.root, msgs),
             self.mesh)
-        tot = (counts @ self.leaf_values).astype(jnp.float32)
-        cnt = jnp.sum(counts[:, :self.tree0_leaves], axis=1).astype(jnp.float32)
-        return tot, cnt
+        return contract_leaves(counts, self.leaf_values, self.tree0_leaves)
 
 
 class Snapshot:
@@ -589,10 +581,8 @@ class Snapshot:
         o = self._owner
         if o.counter is not None:
             o.counter.bump(1)
-        counts = self._counts(group_by)
-        tot = (counts @ self.leaf_values).astype(jnp.float32)
-        cnt = jnp.sum(counts[:, :o.tree0_leaves], axis=1).astype(jnp.float32)
-        return tot, cnt
+        return contract_leaves(self._counts(group_by), self.leaf_values,
+                               o.tree0_leaves)
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         with self._lock:

@@ -8,6 +8,6 @@ from .count_sketch import count_sketch
 from .ref import count_sketch_ref  # noqa: F401
 
 
-def count_sketch_op(x: jnp.ndarray, h: Hash2, interpret: bool = True) -> jnp.ndarray:
+def count_sketch_op(x: jnp.ndarray, h: Hash2, interpret=None) -> jnp.ndarray:
     idx = jnp.arange(x.shape[0])
     return count_sketch(x, h.bucket(idx), h.sign(idx), h.k, interpret=interpret)

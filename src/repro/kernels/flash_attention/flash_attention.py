@@ -27,6 +27,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m, l, *, causal, qc, kc, nk, scale):
     qi = pl.program_id(1)
@@ -64,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m, l, *, causal, qc, kc, nk, scale)
     jax.jit, static_argnames=("causal", "q_block", "kv_block", "interpret")
 )
 def flash_attention(q, k, v, causal: bool = True, q_block: int = 128,
-                    kv_block: int = 128, interpret: bool = True):
+                    kv_block: int = 128, interpret=None):
     """q/k/v: (BH, S, dh) → (BH, S, dh).  S padded to block multiples
     (padding keys are masked out by the causal/position test when causal;
     for non-causal the caller must pass S % kv_block == 0)."""
@@ -98,6 +100,6 @@ def flash_attention(q, k, v, causal: bool = True, q_block: int = 128,
             pltpu.VMEM((qc,), jnp.float32),
             pltpu.VMEM((qc,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :S]

@@ -7,7 +7,7 @@ from .flash_attention import flash_attention
 from .ref import flash_attention_ref  # noqa: F401
 
 
-def flash_attention_gqa(q, k, v, causal=True, interpret=True,
+def flash_attention_gqa(q, k, v, causal=True, interpret=None,
                         q_block=128, kv_block=128):
     """q: (B, S, N, dh); k/v: (B, S, Kh, dh) → (B, S, N·dh)."""
     B, S, N, dh = q.shape

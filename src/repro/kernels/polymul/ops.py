@@ -7,7 +7,7 @@ from .polymul import poly_mul
 from .ref import poly_mul_ref  # noqa: F401  (re-exported oracle)
 
 
-def poly_mul_op(a: jnp.ndarray, b: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def poly_mul_op(a: jnp.ndarray, b: jnp.ndarray, interpret=None) -> jnp.ndarray:
     """Circular conv mod z^k over trailing axis; leading dims flattened
     into the kernel batch."""
     shape = jnp.broadcast_shapes(a.shape, b.shape)

@@ -12,10 +12,12 @@ Grid: one program per batch tile.  VMEM per program:
   = (2·bt·k + k² + bt·k) · 4 B ≤ ~0.5 MB at bt=64, k=256 — well inside
   the ~16 MB VMEM budget; k is padded to the 128-lane boundary upstream.
 
-Building C(a) in-kernel: broadcasted-iota row/col indices, gather-free
-formulation via jnp.take along the flattened (i−j) mod k index — in
-interpret mode this runs the same Python; on TPU Mosaic lowers it to
-vector shuffles.
+Building C(a) in-kernel: broadcasted-iota row/col indices and a
+jnp.take along the (i−j) mod k index.  Mosaic does not lower that
+gather (compiling for a TPU v5e fails with "Shape mismatch in input,
+indices and output"), so the kernel runs in interpret mode only.  No
+relational path calls it: PolyCoeff/PolyFreq multiply through the FFT
+(core/semiring.py).
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, o_ref, *, k: int):
@@ -42,7 +46,7 @@ def _kernel(a_ref, b_ref, o_ref, *, k: int):
 
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
 def poly_mul(a: jnp.ndarray, b: jnp.ndarray, batch_tile: int = 8,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret=None) -> jnp.ndarray:
     """a, b: (B, k) → (B, k) circular products.  k should be a power of
     two (the sketch guarantees this); B is padded to the tile."""
     B, k = a.shape
@@ -61,6 +65,6 @@ def poly_mul(a: jnp.ndarray, b: jnp.ndarray, batch_tile: int = 8,
             pl.BlockSpec((bt, k), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bt, k), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
     return out[:B]

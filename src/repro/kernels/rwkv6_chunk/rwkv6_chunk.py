@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, c, hs):
     ci = pl.program_id(1)
@@ -57,7 +59,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, c, hs):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_chunk(r, k, v, logw, u, chunk: int = 16, interpret: bool = True):
+def rwkv6_chunk(r, k, v, logw, u, chunk: int = 16, interpret=None):
     """r/k/v/logw: (B, S, H, hs) f32; u: (H, hs).  S % chunk == 0.
     Returns (B, S, H, hs)."""
     B, S, H, hs = r.shape
@@ -78,6 +80,6 @@ def rwkv6_chunk(r, k, v, logw, u, chunk: int = 16, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, chunk, hs), lambda b, i: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rf, kf, vf, wf, uf)
     return out.reshape(B, H, S, hs).transpose(0, 2, 1, 3)

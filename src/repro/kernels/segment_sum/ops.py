@@ -14,7 +14,7 @@ from .segment_sum import segment_sum_2d
 
 
 def segment_sum_op(vals: jnp.ndarray, ids: jnp.ndarray, n_keys: int,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret=None) -> jnp.ndarray:
     if vals.ndim == 1:
         return segment_sum_2d(vals[:, None], ids, n_keys, interpret=interpret)[:, 0]
     if vals.ndim == 2 and vals.dtype in (jnp.float32, jnp.bfloat16):

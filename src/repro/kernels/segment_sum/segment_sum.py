@@ -6,14 +6,15 @@ over stacked leaf channels c.  Like count_sketch, a random scatter-add
 serializes through scalar memory on TPU, so the kernel reformulates each
 row tile's contribution as a **one-hot × value matmul** on the MXU:
 
-    msg_tile[key, c] = Σ_r onehot(ids[r])[key] · vals[r, c]
-                     = onehot_matrixᵀ · vals_tile
+    msg[kblk, c] += onehot(kblk, tile) · vals[tile, c]
 
-The grid walks row tiles; the (n_keys, channels) output block is
-revisited across grid steps and accumulated in place (Pallas guarantees
-sequential grid order on TPU, so the read-modify-write is safe).
-VMEM: vals tile (nt × c) + one-hot (nt × n_keys) f32 + output block
-(n_keys × c) — ≤ ~2 MB at nt=256, n_keys=2048, c=64.
+The grid is (key blocks, row tiles).  Each key block's (kb, c) output
+block stays resident while the inner axis walks the row tiles and
+accumulates in place (Pallas runs the grid in order on TPU, so the
+read-modify-write is safe); the one-hot is built per (key block, tile),
+so VMEM holds (kb × tile) + (tile × c) + (kb × c) floats whatever the
+number of keys — about 6 MB at kb = tile = 1024, c = 128.  The row ids
+travel as a (1, n) row so their block is lane-aligned.
 """
 from __future__ import annotations
 
@@ -23,46 +24,54 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
 
-def _kernel(v_ref, i_ref, o_ref, *, n_keys: int):
-    t = pl.program_id(0)
-    v = v_ref[...]                                   # (nt, c)
-    ids = i_ref[...]                                 # (nt,)
-    oh = (ids[:, None] == jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], n_keys), 1))
-    contrib = jnp.dot(
-        oh.astype(jnp.float32).T, v,
-        preferred_element_type=jnp.float32,
-    )                                                # (n_keys, c)
+TILE = 1024          # rows per grid step
+KEY_BLOCK = 1024     # keys per output block
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _kernel(i_ref, v_ref, o_ref):
+    kblk, t = pl.program_id(0), pl.program_id(1)
+    kb, tile = o_ref.shape[0], i_ref.shape[1]
+    keys = kblk * kb + jax.lax.broadcasted_iota(jnp.int32, (kb, tile), 0)
+    oh = (keys == i_ref[...]).astype(jnp.float32)            # (kb, tile)
+    contrib = jnp.dot(oh, v_ref[...], preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)   # (kb, c)
 
     @pl.when(t == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += contrib.astype(o_ref.dtype)
+    o_ref[...] += contrib
 
 
-@functools.partial(jax.jit, static_argnames=("n_keys", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_keys", "interpret"))
 def segment_sum_2d(vals: jnp.ndarray, ids: jnp.ndarray, n_keys: int,
-                   tile: int = 256, interpret: bool = True) -> jnp.ndarray:
+                   interpret=None) -> jnp.ndarray:
     """vals: (n, c) f32, ids: (n,) int32 in [0, n_keys) → (n_keys, c).
 
-    n is padded to the tile; padded rows carry value 0 so they contribute
-    nothing regardless of their (zero-padded) key.
-    """
+    Rows are padded to the tile and keys to the key block; padded rows
+    carry value 0 so they add nothing to whatever key they name."""
     n, c = vals.shape
-    pad = (-n) % tile
-    if pad:
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-        ids = jnp.pad(ids, (0, pad))
-    grid = (vals.shape[0] // tile,)
-    return pl.pallas_call(
-        functools.partial(_kernel, n_keys=n_keys),
-        out_shape=jax.ShapeDtypeStruct((n_keys, c), jnp.float32),
-        grid=grid,
+    tile = min(TILE, _round_up(max(n, 1), 128))
+    kb = min(KEY_BLOCK, _round_up(n_keys, 8))
+    n_pad, k_pad = _round_up(max(n, 1), tile), _round_up(n_keys, kb)
+    vals = jnp.pad(vals.astype(jnp.float32), ((0, n_pad - n), (0, 0)))
+    ids = jnp.pad(ids.astype(jnp.int32), (0, n_pad - n)).reshape(1, n_pad)
+    out = pl.pallas_call(
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((k_pad, c), jnp.float32),
+        grid=(k_pad // kb, n_pad // tile),
         in_specs=[
-            pl.BlockSpec((tile, c), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((1, tile), lambda k, t: (0, t)),
+            pl.BlockSpec((tile, c), lambda k, t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((n_keys, c), lambda i: (0, 0)),
-        interpret=interpret,
-    )(vals.astype(jnp.float32), ids.astype(jnp.int32))
+        out_specs=pl.BlockSpec((kb, c), lambda k, t: (k, 0)),
+        interpret=resolve_interpret(interpret),
+        name="segment_sum_2d",
+    )(ids, vals)
+    return out[:n_keys]

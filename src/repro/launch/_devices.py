@@ -1,12 +1,19 @@
-"""Pre-JAX-import device-count forcing for the launch CLIs.
+"""Pre-JAX-import environment for the launch CLIs and `chip_smoke.py`.
 
-`--devices N` multiplies one host CPU into N XLA devices via
-`--xla_force_host_platform_device_count` — the standard way to prove
-mesh-sharded programs without hardware.  The flag only works if it is
-in `XLA_FLAGS` **before** the first `import jax` anywhere in the
-process, so each CLI module calls :func:`apply_early_device_flags` as
-its very first import, ahead of every `repro.*` import that pulls jax
-in.  (`python -m repro.launch.X` executes no package-level code first:
+Two settings only take effect if they are in the environment **before**
+the first `import jax` anywhere in the process:
+
+- the persistent compilation cache (:func:`configure_compile_cache`):
+  `$JAX_COMPILATION_CACHE_DIR` when set, else a fixed `.jax_cache/` at
+  the checkout root, so a second run on the same machine skips the
+  compiles the first one paid for;
+- `--devices N`, which multiplies one host CPU into N XLA devices via
+  `--xla_force_host_platform_device_count` — the standard way to prove
+  mesh-sharded programs without hardware.
+
+So each CLI module calls :func:`apply_early_device_flags` as its very
+first import, ahead of every `repro.*` import that pulls jax in.
+(`python -m repro.launch.X` executes no package-level code first:
 `repro`/`repro.launch` are namespace packages.)
 
 This module itself must therefore import nothing but the stdlib.
@@ -17,16 +24,34 @@ import os
 import sys
 import warnings
 
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    A `JAX_COMPILATION_CACHE_DIR` set from outside is used as is;
+    otherwise the cache is `.jax_cache/` at the checkout root.  The path
+    is fixed (no temporary name, pid or time in it) because it is part
+    of the cache's key.  Must run before jax is imported."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+            CHECKOUT_ROOT, ".jax_cache")
+    return os.environ["JAX_COMPILATION_CACHE_DIR"]
+
 
 def apply_early_device_flags(argv=None) -> int:
-    """Scan argv for ``--devices N`` / ``--devices=N`` and, when found,
-    append the forced-host-device flag to ``XLA_FLAGS``.  Returns the
-    requested count (0 = flag absent, leave the platform alone).
+    """Place the compile cache, then scan argv for ``--devices N`` /
+    ``--devices=N`` and, when found, append the forced-host-device flag
+    to ``XLA_FLAGS``.  Returns the requested count (0 = flag absent,
+    leave the platform alone).
 
     Must run before jax is imported; if it already is, the request
     cannot take effect and a warning says so instead of silently running
     single-device.
     """
+    configure_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     n = 0
     for i, a in enumerate(argv):

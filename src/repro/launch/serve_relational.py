@@ -272,13 +272,12 @@ def main(argv=None):
                             args.zipf, registry, schema, args, counter,
                             telemetry=telemetry, hot_swap=follower is None))
     if follower is not None:
-        try:
-            follower.stop(drain=True)
-            print(f"follower: applied through lsn {follower.applied_lsn}, "
-                  f"replication lag {follower.replication_lag_s():.3f}s, "
-                  f"writer idle {follower.writer_idle_s():.1f}s")
-        except Exception as e:           # noqa: BLE001 — report, don't die
-            print(f"follower stopped with error: {e}")
+        # a replica that failed to drain served a log it could not apply:
+        # the error propagates and the process exits non-zero
+        follower.stop(drain=True)
+        print(f"follower: applied through lsn {follower.applied_lsn}, "
+              f"replication lag {follower.replication_lag_s():.3f}s, "
+              f"writer idle {follower.writer_idle_s():.1f}s")
     if sampler is not None:
         sampler.stop()
         print(f"wrote {sampler.samples} telemetry samples to {args.sample}")

@@ -36,28 +36,25 @@ SCHEMA_VERSION = 1
 def fingerprint() -> dict:
     """Machine/config identity a report was measured on — enough to
     judge whether two trajectory points are comparable."""
+    import jax
+
+    from ..distributed import spmd
+
+    dev = jax.devices()[0]
     fp = {
-        "platform": platform.platform(),
+        "host": platform.platform(),
         "python": platform.python_version(),
         "cpus": os.cpu_count(),
+        "jax": jax.__version__,
+        # the device a result was measured on, as JAX reports it: a CPU
+        # run can never pass for a chip run
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": jax.device_count(),
     }
-    try:
-        import jax
-        fp["jax"] = jax.__version__
-        fp["device"] = jax.devices()[0].device_kind
-        fp["backend"] = jax.default_backend()
-        # device-tagged entries: forced-host-device CI legs and real
-        # hardware runs both land with their parallel width recorded
-        fp["device_count"] = jax.device_count()
-        try:
-            from ..distributed import spmd
-            mesh = spmd.mesh_fingerprint()
-            if mesh is not None:        # active data mesh at report time
-                fp["mesh"] = mesh
-        except Exception:
-            pass
-    except Exception:
-        fp["jax"] = None
+    mesh = spmd.mesh_fingerprint()
+    if mesh is not None:                # active data mesh at report time
+        fp["mesh"] = mesh
     return fp
 
 

@@ -39,11 +39,9 @@ __all__ = [
 
 def _under_jit_trace() -> bool:
     """True when called from inside a jax trace (jit/vmap staging)."""
-    try:
-        import jax.core
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    import jax.core
+
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class Tracer:
@@ -168,12 +166,10 @@ class _Span:
         tr = self.tracer
         tr._stack().append(self)
         if tr.jax_annotations:
-            try:
-                import jax.profiler
-                self._jax_cm = jax.profiler.TraceAnnotation(self.name)
-                self._jax_cm.__enter__()
-            except Exception:
-                self._jax_cm = None
+            import jax.profiler
+
+            self._jax_cm = jax.profiler.TraceAnnotation(self.name)
+            self._jax_cm.__enter__()
         self.traced = _under_jit_trace()
         self.cpu0 = time.process_time()
         self.t0 = time.perf_counter()
@@ -204,10 +200,7 @@ class _Span:
         ev.update(self.attrs)
         tr.record(ev)
         if self._jax_cm is not None:
-            try:
-                self._jax_cm.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
+            self._jax_cm.__exit__(exc_type, exc, tb)
         return False
 
 

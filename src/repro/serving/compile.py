@@ -27,6 +27,7 @@ sum — optionally routed through the Pallas one-hot-matmul kernel
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -46,18 +47,34 @@ class KernelChannels(Channels):
     Under an active multi-device data mesh the Pallas route falls back to
     the dense ``segment_sum`` — `pallas_call` is a single-device program
     and would force an all-gather of the row-sharded factor; the XLA
-    scatter path partitions cleanly instead."""
-
-    interpret: bool = True
+    scatter path partitions cleanly instead.  The kernel runs in the
+    Pallas interpreter only off the TPU (`kernels.resolve_interpret`)."""
 
     def segment_add(self, vals, segment_ids, num_segments):
         from ..kernels.segment_sum.ops import segment_sum_op
 
         if (vals.ndim == 2 and vals.dtype == jnp.float32
                 and spmd.data_axis_size() <= 1):
-            return segment_sum_op(vals, segment_ids, num_segments,
-                                  interpret=self.interpret)
+            return segment_sum_op(vals, segment_ids, num_segments)
         return super().segment_add(vals, segment_ids, num_segments)
+
+
+@partial(jax.jit, static_argnames="tree0_leaves")
+def contract_leaves(counts: jnp.ndarray, leaf_values: jnp.ndarray,
+                    tree0_leaves: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(Σŷ, |ρ⋈J|) per row from grouped leaf counts (n_g, A).
+
+    The leaf-value contraction is an explicitly sequenced per-row FMA
+    chain in f32, never a matmul: each output row reads only its own
+    counts row, so row sharding cannot move the bits (a gemv's blocking
+    varies with the local row count), and a TPU cannot round the leaf
+    values to bf16 as its default-precision f32 matmul does.  Counts
+    are integer-valued, so the cnt reduction is exact in any order."""
+    tot = counts[:, 0] * leaf_values[0]
+    for j in range(1, int(leaf_values.shape[0])):
+        tot = tot + counts[:, j] * leaf_values[j]
+    cnt = jnp.sum(counts[:, :tree0_leaves], axis=1)
+    return tot.astype(jnp.float32), cnt.astype(jnp.float32)
 
 
 def stack_table_factor(
@@ -152,21 +169,11 @@ class CompiledEnsemble:
             @jax.jit
             def run(factors, vals):
                 counts = sp(sem, factors, group_by=group_by)   # (n_g, A)
-                # contract over the (never-sharded) leaf axis as an
-                # explicitly sequenced FMA chain: each output row reads
-                # only its own counts row, so row sharding cannot move
-                # the bits — unlike a gemv, whose A-contraction blocking
-                # varies with the local row count.  The rows therefore
-                # stay sharded through the whole pass; only the two
-                # (n_g,) results are gathered back.
-                tot = counts[:, 0] * vals[0]
-                for j in range(1, int(vals.shape[0])):
-                    tot = tot + counts[:, j] * vals[j]
-                # integer-valued counts: the cnt reduction is exact in
-                # f32 in any association order
-                cnt = jnp.sum(counts[:, :L0], axis=1)
-                return (spmd.replicate(tot.astype(jnp.float32), mesh),
-                        spmd.replicate(cnt.astype(jnp.float32), mesh))
+                # the rows stay sharded through the whole pass; only the
+                # two (n_g,) results are gathered back
+                tot, cnt = contract_leaves(counts, vals, L0)
+                return (spmd.replicate(tot, mesh),
+                        spmd.replicate(cnt, mesh))
 
             self._score_fns[group_by] = run
         return self._score_fns[group_by]

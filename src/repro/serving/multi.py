@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from ..core.semiring import Channels
 from ..core.sumprod import QueryCounter, SumProd
-from .compile import CompiledEnsemble
+from .compile import CompiledEnsemble, contract_leaves
 
 
 @dataclasses.dataclass
@@ -60,11 +60,8 @@ class StackedEnsembles:
                 counts = sp(sem, factors, group_by=group_by)   # (n_g, ΣA)
                 out = []
                 for (lo, hi, l0) in spans:
-                    c = counts[:, lo:hi]
-                    out.append((
-                        (c @ vals[lo:hi]).astype(jnp.float32),
-                        jnp.sum(c[:, :l0], axis=1).astype(jnp.float32),
-                    ))
+                    out.append(contract_leaves(counts[:, lo:hi],
+                                               vals[lo:hi], l0))
                 return out
 
             self._score_fns[group_by] = run

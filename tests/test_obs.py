@@ -140,6 +140,27 @@ def test_span_exception_safety_with_duplicate_names():
     assert next(e for e in tr.events if e["name"] == "after")["depth"] == 0
 
 
+def test_span_under_jit_trace_is_tagged_traced():
+    """A span opened while jit stages a function records trace time,
+    not runtime, and says so; the same span outside any trace does not."""
+    import jax
+
+    tr = enable_tracing()
+
+    @jax.jit
+    def f(x):
+        with span("staged"):
+            return x * 2.0
+
+    f(jnp.ones(3)).block_until_ready()
+    with span("eager"):
+        pass
+    disable_tracing()
+    ev = {e["name"]: e for e in tr.events}
+    assert ev["staged"].get("traced") is True
+    assert "traced" not in ev["eager"]
+
+
 def test_disabled_span_is_shared_noop():
     assert span("a", x=1) is span("b")          # no allocation when off
     n = 100_000

@@ -73,6 +73,22 @@ def test_score_grouped_every_table(star, star_trees):
         np.testing.assert_allclose(np.asarray(cnt), want_cnt, rtol=1e-5)
 
 
+def test_dead_node_routes_left(star):
+    """A dead internal node (feat -1, thr +inf) sends every row to its
+    left child, as predict_rows does: its rows count once, not in both
+    children's leaves."""
+    from repro.core.tree import TreeArrays
+
+    sch, J, X, y = star
+    tree = TreeArrays(feat=jnp.asarray([0, -1, 2], jnp.int32),
+                      thr=jnp.asarray([0.0, np.inf, 0.0], jnp.float32),
+                      leaf=jnp.asarray([1.0, 2.0, 3.0, 4.0], jnp.float32))
+    tot, cnt = score_grouped(compile_ensemble(sch, [tree]), "fact")
+    want_tot, want_cnt = _oracle(sch, J, X, [tree], "fact")
+    np.testing.assert_allclose(np.asarray(cnt), want_cnt, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(tot), want_tot, rtol=1e-3, atol=1e-3)
+
+
 def test_kernel_routed_scoring_matches(star, star_trees):
     sch, J, X, y = star
     trees = star_trees[:2]

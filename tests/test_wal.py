@@ -130,8 +130,8 @@ def test_record_roundtrip_identity_property(lsn, dele, vals):
 
 
 @settings(max_examples=25, deadline=None)
-@given(st.lists(st.integers(min_value=1, max_value=6),
-                min_size=1, max_size=20))
+@given(sizes=st.lists(st.integers(min_value=1, max_value=6),
+                      min_size=1, max_size=20))
 def test_lsn_monotonic_property(sizes, tmp_path_factory):
     """Property: whatever batch sizes arrive, the log carries strictly
     consecutive LSNs and the writer refuses any other sequence."""
