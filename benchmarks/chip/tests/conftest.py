@@ -1,0 +1,8 @@
+"""The benchmark's own tests: ``python -m pytest benchmarks/chip/tests``.
+They run on the CPU (``JAX_PLATFORMS=cpu``) at tiny sizes."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+sys.path.insert(0, str(BENCH.parents[1] / "src"))
