@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from ..distributed import spmd as _spmd
 from ..obs import metrics as _metrics
-from ..obs.trace import span as _span
+from ..obs.trace import scope as _scope
 from .schema import Schema, JoinTree
 from .semiring import Semiring
 
@@ -211,10 +211,10 @@ class SumProd:
         if jt is None:
             jt = self.schema.join_tree(root)
         msgs: List[Optional[jnp.ndarray]] = [None] * len(jt.edges)
-        with _span("sumprod.messages", n_edges=len(jt.edges)):
+        with _scope("sumprod.messages", n_edges=len(jt.edges)):
             for i, e in enumerate(jt.edges):
-                with _span("sumprod.emit", edge=i, child=e.child,
-                           parent=e.parent, n_keys=e.n_keys):
+                with _scope("sumprod.emit", edge=i, child=e.child,
+                            parent=e.parent, n_keys=e.n_keys):
                     cf = self.node_factor(sem, factors, jt, e.child, msgs)
                     msgs[i] = _spmd.psum_message(
                         sem.segment_add(cf, e.child_ids, e.n_keys))
@@ -240,14 +240,14 @@ class SumProd:
         """
         plan = refresh_plan(jt, dirty)
         new = list(msgs)
-        with _span("sumprod.refresh", n_edges=sum(plan)):
+        with _scope("sumprod.refresh", n_edges=sum(plan)):
             for i, e in enumerate(jt.edges):
                 if new[i].shape[0] < e.n_keys:
                     pad = sem.zeros((e.n_keys - new[i].shape[0],))
                     new[i] = jnp.concatenate([new[i], pad], axis=0)
                 if plan[i]:
-                    with _span("sumprod.emit", edge=i, child=e.child,
-                               parent=e.parent, n_keys=e.n_keys):
+                    with _scope("sumprod.emit", edge=i, child=e.child,
+                                parent=e.parent, n_keys=e.n_keys):
                         cf = self.node_factor(sem, factors, jt, e.child, new)
                         new[i] = _spmd.psum_message(
                             sem.segment_add(cf, e.child_ids, e.n_keys))
@@ -294,8 +294,8 @@ class SumProd:
                     cache.put(jt.root, i, sig, hit)
                 msgs[i] = hit
                 continue
-            with _span("sumprod.emit", edge=i, child=e.child,
-                       parent=e.parent, n_keys=e.n_keys):
+            with _scope("sumprod.emit", edge=i, child=e.child,
+                        parent=e.parent, n_keys=e.n_keys):
                 cf = self.node_factor(sem, factors, jt, e.child, msgs)
                 msgs[i] = _spmd.psum_message(
                     self._segment_add_any(sem, cf, e.child_ids, e.n_keys))

@@ -41,13 +41,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..obs import fence, get_registry, span
+from ..obs import fence, get_registry, scope, span, tracing_enabled
 from .engine import DirectEngine, QueryEngine
 from .hist import build_hist_plans, refresh_hist_plans
 from .schema import Schema
@@ -83,16 +82,35 @@ def _jit_hoisting_consts(fn):
     into it as an HLO constant.  The level step closes over the engine's
     base factors (a 2^20-row sketch factor alone is 1 GB) and one program
     compiles per (level, #prev-leaves): embedded, each program would
-    hold its own copy of every factor in device memory."""
+    hold its own copy of every factor in device memory.
+
+    Each program is named ``level_step`` and built ahead of time in a
+    ``boost.level_build`` scope; the gauge ``train.level_program_bytes``
+    holds the largest device footprint (temporaries, arguments and
+    outputs less aliases, from ``memory_analysis``) of those built."""
     cache = {}
+    largest = 0
 
     def call(*args):
+        nonlocal largest
         flat, tree = jax.tree.flatten(args)
-        key = (tree, tuple((a.shape, a.dtype) for a in flat))
+        key = (tree, tuple((a.shape, a.dtype, getattr(a, "sharding", None))
+                           for a in flat))
         if key not in cache:
-            closed, out = jax.make_jaxpr(fn, return_shape=True)(*args)
-            cache[key] = (jax.jit(partial(jax.core.eval_jaxpr, closed.jaxpr)),
-                          closed.consts, jax.tree.structure(out))
+            with scope("boost.level_build"):
+                closed, out = jax.make_jaxpr(fn, return_shape=True)(*args)
+
+                def level_step(consts, *flat):
+                    return jax.core.eval_jaxpr(closed.jaxpr, consts, *flat)
+
+                run = jax.jit(level_step).lower(closed.consts, *flat).compile()
+            mem = run.memory_analysis()
+            if mem is not None:
+                largest = max(largest, mem.temp_size_in_bytes
+                              + mem.argument_size_in_bytes
+                              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+                get_registry().gauge("train.level_program_bytes").set(largest)
+            cache[key] = (run, closed.consts, jax.tree.structure(out))
         run, consts, out_tree = cache[key]
         return jax.tree.unflatten(out_tree, run(consts, *flat))
 
@@ -229,15 +247,15 @@ class Booster:
             pair = self._loop(M * M, pair_body, jnp.zeros_like(sy))
             ssr_rho = uy - 2.0 * cross + pair
         elif self.cfg.mode == "sketch":
-            resid = self._grouped_sketch(table, masks, labeled=True)  # (K,n_t,kc)
-
             def sk_body(a, acc):
                 extra = {tn: prev_masks[tn][a] for tn in prev_masks}
                 s = self._grouped_sketch(table, masks, extra=extra)
                 return acc - self.sem.scale(s, jnp.zeros(()) + prev_vals[a])
 
-            resid = self._loop(M, sk_body, resid)
-            ssr_rho = self.sem.norm_sq(resid)
+            with scope("boost.sketch", table=table):
+                resid = self._grouped_sketch(table, masks, labeled=True)  # (K,n_t,kc)
+                resid = self._loop(M, sk_body, resid)
+                ssr_rho = self.sem.norm_sq(resid)
         else:
             raise ValueError(self.cfg.mode)
         return n, sum_r, jnp.sum(ssr_rho, axis=1)
@@ -251,13 +269,13 @@ class Booster:
         node_n = None
         for i, tn in enumerate(self.plans):
             want_ssr = cfg.ssr_mode == "per_table" or (cfg.ssr_mode == "once" and i == 0)
-            with span("boost.stats", table=tn):
+            with scope("boost.stats", table=tn):
                 n, s, ssr = self._table_stats(tn, masks, prev_masks, prev_vals, want_ssr)
             if i == 0:
                 node_n = jnp.sum(n, axis=1)
             if ssr is not None:
                 ssr_out[tn] = ssr
-            with span("boost.sweep", table=tn, mode=cfg.split_mode):
+            with scope("boost.sweep", table=tn, mode=cfg.split_mode):
                 results.append(fence(best_split_for_table(self.plans[tn], n, s)))
         best: SplitResult = merge_table_results(results)
 
@@ -267,11 +285,12 @@ class Booster:
         lm = jnp.where(valid, best.left_sum / jnp.maximum(best.left_cnt, 1e-9), node_mean)
         rm = jnp.where(valid, best.right_sum / jnp.maximum(best.right_cnt, 1e-9), node_mean)
         new_mean = jnp.stack([lm, rm], axis=1).reshape(-1)
-        new_masks = {
-            tn: descend_masks_level(self.schema, tn, feat, thr, masks[tn],
-                                    featmat=self.engine.mask_featmat(tn))
-            for tn in masks
-        }
+        with scope("boost.descend"):
+            new_masks = {
+                tn: descend_masks_level(self.schema, tn, feat, thr, masks[tn],
+                                        featmat=self.engine.mask_featmat(tn))
+                for tn in masks
+            }
         return feat, thr, new_mean, new_masks, ssr_out, node_n
 
     def _leaf_masks_impl(self, tree: TreeArrays):
@@ -304,12 +323,13 @@ class Booster:
     def _fit_tree(self, prev_trees: List[TreeArrays], trace: FitTrace) -> TreeArrays:
         cfg, schema = self.cfg, self.schema
         if prev_trees:
-            per_tree = [self._leaf_masks(pt) for pt in prev_trees]
-            prev_masks = {
-                t.name: jnp.concatenate([pm[t.name] for pm in per_tree])
-                for t in schema.tables
-            }
-            prev_vals = jnp.concatenate([pt.leaf for pt in prev_trees])
+            with scope("boost.prev_masks", trees=len(prev_trees)):
+                per_tree = [self._leaf_masks(pt) for pt in prev_trees]
+                prev_masks = {
+                    t.name: jnp.concatenate([pm[t.name] for pm in per_tree])
+                    for t in schema.tables
+                }
+                prev_vals = jnp.concatenate([pt.leaf for pt in prev_trees])
         else:
             prev_masks = {
                 t.name: jnp.zeros((0, self.engine.n_rows(t.name)), jnp.bool_)
@@ -327,13 +347,15 @@ class Booster:
 
         for level in range(cfg.depth):
             t0 = time.perf_counter()
-            with span("boost.level", level=level, prev_leaves=M):
+            fenced = tracing_enabled()
+            with scope("boost.level", level=level, prev_leaves=M):
                 feat, thr, node_mean, masks, ssr, node_n = self._level_step(
                     masks, prev_masks, prev_vals, node_mean
                 )
                 fence((feat, thr, node_mean))
-            get_registry().histogram("train.level_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
+            if fenced:      # unfenced, the timer would read dispatch time
+                get_registry().histogram("train.level_ms").observe(
+                    (time.perf_counter() - t0) * 1e3)
             start = 2 ** level - 1
             tree = TreeArrays(
                 feat=jax.lax.dynamic_update_slice_in_dim(tree.feat, feat, start, 0),

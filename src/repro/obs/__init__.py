@@ -8,7 +8,9 @@ three pieces every subsystem reports through:
   :class:`Tracer` with JSONL + Chrome-trace (Perfetto) export,
   ``jax.profiler`` annotation passthrough, and :func:`fence` for
   explicit ``block_until_ready`` attribution.  Default-off: disabled
-  spans are a shared no-op context manager.
+  spans are a shared no-op context manager.  ``scope("name")`` names
+  device-path work: a ``jax.named_scope`` under jit, a profiler
+  annotation (and, while tracing, a span) when run eagerly.
 - :mod:`.metrics` — a thread-safe :class:`MetricsRegistry` of counters,
   gauges, and log-bucketed histograms with snapshot/diff/merge
   semantics; ``QueryCounter``, ``MessageCache``, the serving LRU cache
@@ -43,7 +45,7 @@ from .metrics import (
 from .report import BenchReport, bench_path, fingerprint, validate_bench
 from .slo import SLOMonitor, SLOObjective, parse_slo_spec
 from .trace import (
-    Tracer, disable_tracing, enable_tracing, fence, get_tracer, span,
+    Tracer, disable_tracing, enable_tracing, fence, get_tracer, scope, span,
     tracing_enabled,
 )
 
@@ -52,7 +54,7 @@ __all__ = [
     "diff_snapshots", "merge_snapshots", "format_summary_table",
     "get_registry", "reset_registry",
     "BenchReport", "bench_path", "fingerprint", "validate_bench",
-    "Tracer", "span", "fence", "enable_tracing", "disable_tracing",
+    "Tracer", "span", "scope", "fence", "enable_tracing", "disable_tracing",
     "tracing_enabled", "get_tracer",
     "FlightRecorder",
     "TelemetryServer", "PeriodicSampler", "render_prometheus", "render_json",

@@ -22,6 +22,15 @@ whichever span happens to force the value later.  Fencing only happens
 while tracing is enabled, so the disabled path never serializes
 dispatch.  Span bodies that run under a jit trace are recorded as such
 (``traced=True``) — their duration is compile/trace time, not runtime.
+
+:func:`scope` is the instrument for code that may run either way.
+Staged by jit it is a ``jax.named_scope``: the name lands in the
+compiled ops' ``op_name`` metadata, so a device trace attributes each
+op to it, and no host event is recorded.  Run eagerly it is always a
+``TraceAnnotation`` (any profiler capture carries it on the host
+timeline, on the device trace's clock) and, while tracing is enabled,
+also a recorded span.  Neither depends on the tracing state, so the
+compiled program is the same with tracing on or off.
 """
 from __future__ import annotations
 
@@ -32,7 +41,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 __all__ = [
-    "Tracer", "span", "fence", "enable_tracing", "disable_tracing",
+    "Tracer", "span", "scope", "fence", "enable_tracing", "disable_tracing",
     "tracing_enabled", "get_tracer",
 ]
 
@@ -154,21 +163,23 @@ class Tracer:
 class _Span:
     """Recording context manager (only built while tracing is enabled)."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "cpu0", "traced", "_jax_cm")
+    __slots__ = ("tracer", "name", "attrs", "annotate", "t0", "cpu0", "traced",
+                 "_jax_cm")
 
-    def __init__(self, tracer: Tracer, name: str, attrs: dict):
+    def __init__(self, tracer: Tracer, name: str, attrs: dict, annotate: bool):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
+        self.annotate = annotate
         self._jax_cm = None
 
     def __enter__(self):
         tr = self.tracer
         tr._stack().append(self)
-        if tr.jax_annotations:
+        if self.annotate:
             import jax.profiler
 
-            self._jax_cm = jax.profiler.TraceAnnotation(self.name)
+            self._jax_cm = jax.profiler.TraceAnnotation(self.name, **self.attrs)
             self._jax_cm.__enter__()
         self.traced = _under_jit_trace()
         self.cpu0 = time.process_time()
@@ -229,6 +240,9 @@ def tracing_enabled() -> bool:
 
 
 def enable_tracing(clear: bool = True, jax_annotations: bool = True) -> Tracer:
+    """Switch the process tracer on.  ``jax_annotations`` decides whether
+    :func:`span` also writes a ``TraceAnnotation``; :func:`scope` always
+    does, whatever it says."""
     if clear:
         _tracer.clear()
     _tracer.jax_annotations = jax_annotations
@@ -246,7 +260,25 @@ def span(name: str, **attrs):
     tracing is enabled, otherwise returns the shared no-op manager."""
     if not _tracer.enabled:
         return _NULL
-    return _Span(_tracer, name, attrs)
+    return _Span(_tracer, name, attrs, _tracer.jax_annotations)
+
+
+def scope(name: str, **attrs):
+    """``with scope("boost.sketch", table=t):`` — names device-path work.
+
+    Under a jit trace: ``jax.named_scope(name)`` (the attributes are
+    dropped; ops are named, nothing is recorded).  Eagerly: a
+    ``TraceAnnotation`` carrying ``attrs`` as its stats, recorded as a
+    span as well while tracing is enabled."""
+    if _under_jit_trace():
+        import jax
+
+        return jax.named_scope(name)
+    if not _tracer.enabled:
+        import jax.profiler
+
+        return jax.profiler.TraceAnnotation(name, **attrs)
+    return _Span(_tracer, name, attrs, True)
 
 
 def fence(value: Any) -> Any:

@@ -27,6 +27,7 @@ sum — optionally routed through the Pallas one-hot-matmul kernel
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 from typing import Dict, List, Optional, Tuple
 
@@ -38,6 +39,7 @@ from ..core.semiring import Channels
 from ..core.sumprod import QueryCounter, SumProd
 from ..core.tree import TreeArrays, leaf_masks
 from ..distributed import spmd
+from ..obs import get_registry, scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,32 +162,45 @@ class CompiledEnsemble:
 
     # ----------------------------------------------------------- scoring --
     def _score_fn(self, group_by: str):
-        """Jitted one-pass scorer for one grouping table (compile-once)."""
+        """One-pass scorer program for one grouping table, built ahead of
+        time on first use (``serve.scorer_build``: trace, lower, compile
+        or load from the cache) and kept.  Each build bumps the counter
+        ``serve.scorer_programs`` and adds its host milliseconds to the
+        histogram ``serve.scorer_build_ms``."""
         if group_by not in self._score_fns:
             sp, sem, L0 = self._sp, self._sem, self.tree0_leaves
 
             mesh = self.mesh
 
-            @jax.jit
             def run(factors, vals):
                 counts = sp(sem, factors, group_by=group_by)   # (n_g, A)
                 # the rows stay sharded through the whole pass; only the
                 # two (n_g,) results are gathered back
-                tot, cnt = contract_leaves(counts, vals, L0)
+                with scope("serve.contract"):
+                    tot, cnt = contract_leaves(counts, vals, L0)
                 return (spmd.replicate(tot, mesh),
                         spmd.replicate(cnt, mesh))
 
-            self._score_fns[group_by] = run
+            t0 = time.perf_counter()
+            with scope("serve.scorer_build", group_by=group_by):
+                self._score_fns[group_by] = jax.jit(run).lower(
+                    self.factors, self.leaf_values).compile()
+            reg = get_registry()
+            reg.counter("serve.scorer_programs").inc()
+            reg.histogram("serve.scorer_build_ms").observe(
+                (time.perf_counter() - t0) * 1e3)
         return self._score_fns[group_by]
 
     def score_grouped(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(Σŷ, |ρ⋈J|) per row of ``group_by`` — ONE SumProd evaluation."""
         if self.counter is not None:
             self.counter.bump(1)
-        # trace (first call) must see this ensemble's mesh — psum_message
-        # inside the pass reads the ambient context at trace time
+        # the build must see this ensemble's mesh — psum_message inside
+        # the pass reads the ambient context at trace time
         with spmd.use_data_mesh(self.mesh):
-            return self._score_fn(group_by)(self.factors, self.leaf_values)
+            run = self._score_fn(group_by)
+            with scope("serve.score", group_by=group_by):
+                return run(self.factors, self.leaf_values)
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Memoized full-table scores: tables are static per model version,
@@ -210,10 +225,11 @@ def compile_ensemble(
     the plain single-device program)."""
     if not trees:
         raise ValueError("cannot compile an empty ensemble")
-    factors = {
-        t.name: stack_table_factor(schema, trees, t.name, dtype=factor_dtype)
-        for t in schema.tables
-    }
+    factors = {}
+    for t in schema.tables:
+        with scope("serve.factor", table=t.name):
+            factors[t.name] = stack_table_factor(schema, trees, t.name,
+                                                 dtype=factor_dtype)
     leaf_values = jnp.concatenate([t.leaf for t in trees]).astype(jnp.float32)
     return CompiledEnsemble(
         schema=schema,

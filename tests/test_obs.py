@@ -4,8 +4,10 @@ are free and tracing never changes what training computes.
 """
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
+import logging
 import math
 import os
 import threading
@@ -13,13 +15,14 @@ import time
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import BoostConfig, Booster, Channels, QueryCounter, SumProd
 from repro.obs import (
     BenchReport, Histogram, MetricsRegistry, diff_snapshots,
     disable_tracing, enable_tracing, format_summary_table, get_registry,
-    get_tracer, merge_snapshots, span, validate_bench,
+    get_tracer, merge_snapshots, scope, span, validate_bench,
 )
 from repro.serving.service import ServiceStats
 from repro.relational.generators import star_schema
@@ -161,6 +164,72 @@ def test_span_under_jit_trace_is_tagged_traced():
     assert "traced" not in ev["eager"]
 
 
+def test_scope_under_jit_names_ops_and_records_nothing():
+    """Staged by jit, a scope reaches the compiled ops' ``op_name``
+    metadata (what a device trace attributes ops by) and records no
+    host event, tracing on or not."""
+    tr = enable_tracing()
+
+    def f(x):
+        with scope("probe.scope", table="t"):
+            return jnp.sin(x) * 2.0
+
+    hlo = jax.jit(f).lower(jnp.ones(8)).compile().as_text()
+    disable_tracing()
+    assert "probe.scope/sin" in hlo
+    assert tr.events == []
+
+
+def test_eager_scope_records_only_while_enabled():
+    tr = enable_tracing()
+    disable_tracing()
+    with scope("probe.off", table="t"):
+        pass
+    assert tr.events == []
+    enable_tracing()
+    with scope("probe.on", table="t"):
+        jnp.ones(3).block_until_ready()
+    disable_tracing()
+    (ev,) = tr.events
+    assert ev["name"] == "probe.on" and ev["table"] == "t"
+    assert "traced" not in ev
+
+
+def test_level_program_is_named_level_step(caplog):
+    sch = star_schema(seed=3, n_fact=40, n_dim=6)
+    with caplog.at_level(logging.WARNING), jax.log_compiles():
+        Booster(sch, BoostConfig(n_trees=1, depth=1)).fit()
+    built = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("Compiling jit(")]
+    assert any(m.startswith("Compiling jit(level_step)") for m in built), built
+    assert not any("unknown" in m for m in built), built
+
+
+def test_level_program_bytes_gauge_after_sketch_fit():
+    gauge = get_registry().gauge("train.level_program_bytes")
+    gauge.set(0)
+    sch = star_schema(seed=5, n_fact=40, n_dim=6)
+    Booster(sch, BoostConfig(n_trees=1, depth=1, mode="sketch", sketch_k=8)).fit()
+    assert gauge.value > 0
+
+
+def test_scorer_programs_counted_once_per_compiled_ensemble():
+    from repro.serving import compile_ensemble, score_grouped
+
+    sch = star_schema(seed=6, n_fact=40, n_dim=6)
+    trees, _ = Booster(sch, BoostConfig(n_trees=1, depth=1)).fit()
+    programs = get_registry().counter("serve.scorer_programs")
+    fact = sch.tables[0].name
+    for _ in range(2):
+        n0 = programs.value
+        ens = compile_ensemble(sch, trees)
+        first = score_grouped(ens, fact)
+        assert programs.value == n0 + 1
+        again = score_grouped(ens, fact)
+        assert programs.value == n0 + 1
+        assert np.array_equal(np.asarray(first[0]), np.asarray(again[0]))
+
+
 def test_disabled_span_is_shared_noop():
     assert span("a", x=1) is span("b")          # no allocation when off
     n = 100_000
@@ -262,7 +331,7 @@ def test_edge_accounting_unchanged(star):
 
 # -------------------------------------------- tracing is observation-only --
 
-def test_tracing_does_not_change_trained_trees():
+def test_tracing_does_not_change_trained_trees(monkeypatch):
     sch = star_schema(seed=11, n_fact=120, n_dim=12)
     cfg = BoostConfig(n_trees=2, depth=2, mode="sketch", ssr_mode="off")
     plain, _ = Booster(sch, cfg).fit()
@@ -270,12 +339,26 @@ def test_tracing_does_not_change_trained_trees():
     traced, _ = Booster(sch, cfg).fit()
     tr = disable_tracing()
     assert len(tr.events) > 0               # instrumentation actually fired
-    for a, b in zip(plain, traced):
-        assert np.array_equal(np.asarray(a.feat), np.asarray(b.feat))
-        assert np.array_equal(np.asarray(a.thr), np.asarray(b.thr))
-        assert np.array_equal(np.asarray(a.leaf), np.asarray(b.leaf))
+    # and with every scope taken out of the program
+    import repro.core.sumprod as sumprod_mod
+    import repro.core.trainer as trainer_mod
+
+    unscoped_fn = lambda *a, **k: contextlib.nullcontext()  # noqa: E731
+    monkeypatch.setattr(trainer_mod, "scope", unscoped_fn)
+    monkeypatch.setattr(sumprod_mod, "_scope", unscoped_fn)
+    unscoped, _ = Booster(sch, cfg).fit()
+    for a, b, c in zip(plain, traced, unscoped):
+        for f in ("feat", "thr", "leaf"):
+            want = np.asarray(getattr(a, f))
+            assert np.array_equal(want, np.asarray(getattr(b, f)))
+            assert np.array_equal(want, np.asarray(getattr(c, f)))
     names = {e["name"] for e in tr.events}
-    assert {"boost.round", "boost.sweep", "sumprod.emit"} <= names
+    # eager scopes are recorded; the jitted level step's scopes name
+    # device ops instead of recording host spans
+    assert {"boost.round", "boost.level", "boost.prev_masks",
+            "boost.level_build"} <= names
+    assert not names & {"boost.stats", "boost.sweep", "boost.sketch",
+                        "boost.descend", "sumprod.emit"}
 
 
 # ------------------------------------------------------- service metrics --
