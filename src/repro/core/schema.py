@@ -143,6 +143,15 @@ class Schema:
             for li, _ in enumerate(self.feat_cols[t.name]):
                 self.feat_global.append((ti, li))
         self.n_features = len(self.feat_global)
+        # per table, global feature id → local column (-1 if foreign): a
+        # host constant, so mask descent bakes it in instead of building it
+        self.local_feature_ids: Dict[str, np.ndarray] = {}
+        for ti, t in enumerate(self.tables):
+            g2l = np.full((max(self.n_features, 1),), -1, np.int32)
+            for g, (tg, li) in enumerate(self.feat_global):
+                if tg == ti:
+                    g2l[g] = li
+            self.local_feature_ids[t.name] = g2l
 
         self.labels = jnp.asarray(
             self.tables[self.index[self.label_table]].col(self.label_column).astype(np.float32)

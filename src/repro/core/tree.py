@@ -15,10 +15,12 @@ the ⊗ of factors conjoins them across tables inside the SumProd query).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .schema import Schema
 
@@ -86,14 +88,20 @@ def predict_rows(trees: List[TreeArrays], X: jnp.ndarray, lr: float = 1.0) -> jn
 def _local_feature_view(schema: Schema, table: str, featmat=None):
     """(g2l, featmat): map global feature id → local column, -1 if foreign.
 
-    ``featmat`` overrides the schema's device-resident (n_rows, d_t)
-    matrix — used by incremental maintenance to evaluate masks for just a
-    delta's rows (same columns, arbitrary row subset)."""
-    g2l = -jnp.ones((max(schema.n_features, 1),), jnp.int32)
-    for g, (ti, li) in enumerate(schema.feat_global):
-        if schema.tables[ti].name == table:
-            g2l = g2l.at[g].set(li)
-    return g2l, schema.featmat[table] if featmat is None else featmat
+    ``g2l`` is the schema's host-side int32 table, so a program that
+    descends bakes it in as a constant.  ``featmat`` overrides the
+    schema's device-resident (n_rows, d_t) matrix — used by incremental
+    maintenance to evaluate masks for just a delta's rows (same columns,
+    arbitrary row subset)."""
+    fm = schema.featmat[table] if featmat is None else featmat
+    return schema.local_feature_ids[table], _own_columns(fm)
+
+
+def _own_columns(fm: jnp.ndarray) -> jnp.ndarray:
+    """``fm``, or one column of zeros for a table with no feature: every
+    split is foreign to such a table, so no node reads the column, and a
+    gather needs one to read from."""
+    return fm if fm.shape[1] else jnp.zeros((fm.shape[0], 1), fm.dtype)
 
 
 def descend_masks_level(
@@ -137,6 +145,55 @@ def leaf_masks(schema: Schema, table: str, tree: TreeArrays, featmat=None) -> jn
         feat, thr = tree.level_slice(level)
         m = descend_masks_level(schema, table, feat, thr, m, featmat)
     return m
+
+
+def _pick_columns(fm: jnp.ndarray, col: jnp.ndarray) -> jnp.ndarray:
+    """``fm[r, col[a]]`` for every row r and lane a: a select chain over
+    ``fm``'s few columns, an exact copy that stays elementwise where a
+    gather along the minor axis does not."""
+    vals = fm[:, :1]
+    for j in range(1, int(fm.shape[1])):
+        vals = jnp.where(col == j, fm[:, j:j + 1], vals)
+    return vals
+
+
+def stacked_leaf_masks(g2l, fm: jnp.ndarray, trees: List[TreeArrays]) -> jnp.ndarray:
+    """(n_rows, Σ leaves) bool: every tree's :func:`leaf_masks` over the
+    feature rows ``fm`` (n_rows, d_t), concatenated tree-major and
+    transposed, built in that layout.
+
+    Leaf a of a depth-D tree holds row r iff the terms of its D
+    ancestors all hold, ANDed root first — the chain
+    :func:`descend_masks_level` builds level by level: at a node the
+    left child's term is ``~mine | ~cond`` and the right child's
+    ``alive & (~mine | cond)``, with ``cond = vals >= thr``.  Leaves lie
+    along the minor axis, each lane reading its ancestors' splits, so a
+    level is one elementwise pass over (n_rows, leaves): no gather of
+    rows, no interleave of children and no transpose.  Trees of one
+    depth share a pass."""
+    fm, out = _own_columns(fm), []
+    for depth, run in itertools.groupby(trees, key=lambda t: t.depth):
+        run = list(run)
+        feat = jnp.concatenate([t.feat for t in run])
+        thr = jnp.concatenate([t.thr for t in run])
+        local = jnp.take(g2l, jnp.maximum(feat, 0)) * jnp.where(feat >= 0, 1, 0) + jnp.where(
+            feat >= 0, 0, -1
+        )
+        mine, alive, col = local >= 0, feat >= 0, jnp.maximum(local, 0)
+        L = 1 << depth
+        tree = np.repeat(np.arange(len(run)), L)          # each lane's tree,
+        leaf = np.tile(np.arange(L), len(run))            # and leaf in it
+        m = jnp.ones((fm.shape[0], len(run) * L), jnp.bool_)
+        for level in range(depth):
+            node = tree * (L - 1) + (1 << level) - 1 + (leaf >> (depth - level))
+            right = ((leaf >> (depth - 1 - level)) & 1).astype(bool)
+            vals = _pick_columns(fm, col[node])
+            cond = vals >= thr[node]
+            term = jnp.where(right, alive[node] & (~mine[node] | cond),
+                             ~mine[node] | ~cond)
+            m = m & term
+        out.append(m)
+    return jnp.concatenate(out, axis=1)
 
 
 def all_tables_leaf_masks(schema: Schema, tree: TreeArrays) -> Dict[str, jnp.ndarray]:

@@ -8,8 +8,12 @@ into one channel axis.
 
 For each table T_t the per-leaf membership masks (L, n_rows) of all
 trees concatenate into a single (total_leaves, n_rows) array; its
-transpose, cast to f32, is T_t's factor in a ``Channels(total_leaves)``
-product semiring.  ONE inside-out pass grouped by ρ's table then yields
+transpose, cast to ``factor_dtype``, is T_t's factor in a
+``Channels(total_leaves)`` product semiring.  All tables' factors come
+from ONE program keyed by shapes alone (``_factor_program``): the trees
+and the feature matrices are its arguments, so a new ensemble or a new
+dataset of the same shapes reuses it.  ONE inside-out pass grouped by
+ρ's table then yields
 
     counts[ρ, a] = |{x ∈ ρ ⋈ J : x in leaf a}|        (all a at once)
 
@@ -33,11 +37,12 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.schema import Schema
 from ..core.semiring import Channels
 from ..core.sumprod import QueryCounter, SumProd
-from ..core.tree import TreeArrays, leaf_masks
+from ..core.tree import TreeArrays, stacked_leaf_masks
 from ..distributed import spmd
 from ..obs import get_registry, scope
 
@@ -79,6 +84,36 @@ def contract_leaves(counts: jnp.ndarray, leaf_values: jnp.ndarray,
     return tot.astype(jnp.float32), cnt.astype(jnp.float32)
 
 
+@partial(jax.jit, static_argnames=("views", "dtype"))
+def _factor_program(trees: List[TreeArrays], featmats: Tuple[jnp.ndarray, ...],
+                    views, dtype):
+    """((n_rows, total_leaves) factor per table of ``views``, leaf values).
+
+    Keyed by structure alone: table names and feature maps (``views``),
+    ``dtype``, and the shapes of the arguments (trees, depths, rows,
+    feature widths).  Trees and feature matrices are arguments, so a new
+    ensemble or dataset of the same shapes reuses the program.  Each
+    trace bumps the counter ``serve.factor_programs``."""
+    get_registry().counter("serve.factor_programs").inc()
+    factors = tuple(
+        stacked_leaf_masks(np.asarray(g2l, np.int32), fm, trees).astype(dtype)
+        for (_, g2l), fm in zip(views, featmats))
+    leaf_values = jnp.concatenate([t.leaf for t in trees]).astype(jnp.float32)
+    return factors, leaf_values
+
+
+def _stacked_factors(schema: Schema, trees: List[TreeArrays],
+                     featmats: Dict[str, jnp.ndarray], dtype):
+    """The factor program over ``featmats`` (table → feature rows):
+    ({table: factor}, leaf values).  The tables' feature maps go in as
+    plain ints, the structural half of the program's key."""
+    views = tuple((t, tuple(int(g) for g in schema.local_feature_ids[t]))
+                  for t in featmats)
+    factors, leaf_values = _factor_program(
+        list(trees), tuple(featmats.values()), views=views, dtype=jnp.dtype(dtype))
+    return dict(zip(featmats, factors)), leaf_values
+
+
 def stack_table_factor(
     schema: Schema,
     trees: List[TreeArrays],
@@ -86,13 +121,15 @@ def stack_table_factor(
     featmat: Optional[jnp.ndarray] = None,
     dtype=jnp.float32,
 ) -> jnp.ndarray:
-    """Stacked leaf-mask factor for one table: (n_rows, total_leaves).
+    """Stacked leaf-mask factor for one table: (n_rows, total_leaves),
+    built by the shape-keyed factor program with the trees and the
+    feature matrix as its arguments.
 
     With ``featmat`` (k, d_t), only those k feature rows are evaluated —
     the per-row factor slice incremental maintenance scatters back into a
     live factor after a delta."""
-    per_tree = [leaf_masks(schema, table, t, featmat=featmat) for t in trees]
-    return jnp.concatenate(per_tree, axis=0).T.astype(dtype)
+    fm = schema.featmat[table] if featmat is None else featmat
+    return _stacked_factors(schema, trees, {table: fm}, dtype)[0][table]
 
 
 @dataclasses.dataclass
@@ -218,19 +255,17 @@ def compile_ensemble(
     factor_dtype=jnp.float32,
     mesh=None,
 ) -> CompiledEnsemble:
-    """Stack per-table leaf masks across all trees into channel factors.
+    """Stack per-table leaf masks across all trees into channel factors,
+    every table's in one call of the shape-keyed factor program.
 
     ``mesh``: explicit data mesh, or None to capture the ambient
     `spmd.use_data_mesh` context (still None outside any context —
     the plain single-device program)."""
     if not trees:
         raise ValueError("cannot compile an empty ensemble")
-    factors = {}
-    for t in schema.tables:
-        with scope("serve.factor", table=t.name):
-            factors[t.name] = stack_table_factor(schema, trees, t.name,
-                                                 dtype=factor_dtype)
-    leaf_values = jnp.concatenate([t.leaf for t in trees]).astype(jnp.float32)
+    with scope("serve.factor", tables=len(schema.featmat)):
+        factors, leaf_values = _stacked_factors(schema, trees, schema.featmat,
+                                                factor_dtype)
     return CompiledEnsemble(
         schema=schema,
         trees=list(trees),

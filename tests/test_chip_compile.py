@@ -1,11 +1,12 @@
-"""Compile rehearsals of the relational path's Pallas kernels for a TPU v5e.
+"""Compile rehearsals of the relational path's Pallas kernels, and of its
+serving factor program, for a TPU v5e.
 
 The TPU compiler is installed alongside JAX, so each kernel is lowered
 and compiled here for a *described* v5e:2x2 topology with no chip
 attached, at the main path's real widths (a 2^20-row fact table, 128
 stacked leaf channels, sketch k = 256).  Interpret-mode tests cannot
 catch a block shape Mosaic refuses or a block set that overflows VMEM;
-these do.  Each compiled program must hold the kernel as a
+these do.  Each compiled kernel program must hold the kernel as a
 ``tpu_custom_call``, not a fallback.
 
 The topology is described inside a module fixture (never at import):
@@ -63,3 +64,23 @@ def test_count_sketch_compiles_for_tpu(one_chip):
                    one_chip, ((N_ROWS,), jnp.float32), ((N_ROWS,), jnp.int32),
                    ((N_ROWS,), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+def test_factor_program_compiles_for_tpu_in_one_pass(one_chip):
+    """The serving factor program at the rescore's LINEITEM shape (2^20
+    rows, 11 of 24 features, 8 depth-4 trees) compiles for a v5e and
+    needs no temporary buffer: each leaf's mask is built in the factor's
+    own (rows, leaves) layout, with no level-by-level intermediates."""
+    from repro.core.tree import TreeArrays
+    from repro.serving.compile import _factor_program
+
+    def shape(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    trees = [TreeArrays(shape((15,), jnp.int32), shape((15,), jnp.float32),
+                        shape((16,), jnp.float32)) for _ in range(8)]
+    views = (("lineitem", tuple(range(11)) + (-1,) * 13),)
+    compiled = _factor_program.lower(
+        trees, (shape((N_ROWS, 11), jnp.float32),), views=views,
+        dtype=jnp.dtype(jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

@@ -230,6 +230,39 @@ def test_scorer_programs_counted_once_per_compiled_ensemble():
         assert np.array_equal(np.asarray(first[0]), np.asarray(again[0]))
 
 
+def test_factor_programs_counted_once_per_shape():
+    """One factor program per shape: a new ensemble, or a new dataset of
+    the same shapes, reuses it; another tree count or depth builds one
+    more.  Scores match an ensemble built on the eager per-tree masks."""
+    from _trees import random_trees
+    from repro.core.tree import leaf_masks
+    from repro.serving import CompiledEnsemble, compile_ensemble, score_grouped
+
+    programs = get_registry().counter("serve.factor_programs")
+    sch = star_schema(seed=31, n_fact=46, n_dim=9)    # shapes of this test only
+    n0 = programs.value
+    ensembles = [compile_ensemble(sch, random_trees(sch, (2, 2), seed=s))
+                 for s in (1, 2)]
+    assert programs.value == n0 + 1
+    other = star_schema(seed=32, n_fact=46, n_dim=9)
+    compile_ensemble(other, random_trees(other, (2, 2), seed=3))
+    assert programs.value == n0 + 1
+    compile_ensemble(sch, random_trees(sch, (2, 2, 2), seed=4))
+    assert programs.value == n0 + 2
+    compile_ensemble(sch, random_trees(sch, (3, 3), seed=5))
+    assert programs.value == n0 + 3
+    for ens in ensembles:
+        eager = CompiledEnsemble(
+            schema=sch, trees=ens.trees, leaf_values=ens.leaf_values,
+            factors={t.name: jnp.concatenate(
+                [leaf_masks(sch, t.name, tr) for tr in ens.trees]).T.astype(jnp.float32)
+                for t in sch.tables},
+            tree0_leaves=ens.tree0_leaves)
+        for a, b in zip(score_grouped(ens, "fact"), score_grouped(eager, "fact")):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert programs.value == n0 + 3
+
+
 def test_disabled_span_is_shared_noop():
     assert span("a", x=1) is span("b")          # no allocation when off
     n = 100_000

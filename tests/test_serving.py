@@ -5,14 +5,19 @@ kernel routing; interactive entry points; micro-batching service
 import asyncio
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
+from _trees import random_trees
 from repro.core import BoostConfig, Booster, QueryCounter, predict_rows
+from repro.core.schema import Schema, Table
+from repro.core.tree import leaf_masks
+from repro.relational.generators import star_schema
 from repro.serving import (
     LRUCache, ModelRegistry, RelationalScoringService, compile_ensemble,
     score_fresh, score_grouped, score_grouped_reference, score_mean_rows,
-    score_rows,
+    score_rows, stack_table_factor,
 )
 
 
@@ -87,6 +92,65 @@ def test_dead_node_routes_left(star):
     want_tot, want_cnt = _oracle(sch, J, X, [tree], "fact")
     np.testing.assert_allclose(np.asarray(cnt), want_cnt, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(tot), want_tot, rtol=1e-3, atol=1e-3)
+
+
+def eager_factor(sch, trees, table, featmat=None, dtype=jnp.float32):
+    """A table's factor built op by op, the reference for the factor
+    program: per-tree ``leaf_masks``, concatenated, transposed, cast."""
+    per_tree = [leaf_masks(sch, table, t, featmat=featmat) for t in trees]
+    return jnp.concatenate(per_tree, axis=0).T.astype(dtype)
+
+
+def same_bits(a, b) -> bool:
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+def _star_with_bare_table():
+    """A star whose second dimension has no feature: its factor has no
+    column to read, and every split is foreign to it."""
+    base = star_schema(seed=8, n_fact=90, n_dim=11)
+    tables = []
+    for t in base.tables:
+        tab = Table(t.name, dict(t.columns), feature_columns=t.feature_columns)
+        if t.name == "dim1":
+            tab.feature_columns = ()
+        tables.append(tab)
+    return Schema(tables, label=("fact", "y"))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("fixture", ["star", "chain", "snowflake", "bare_table"])
+def test_factor_program_matches_eager_masks_bit_for_bit(fixture, dtype, request):
+    """The shape-keyed factor program gives the eager build's factors bit
+    for bit: over a whole table, over a subset of its rows (as
+    maintenance evaluates a delta, alone and under its own jit), with
+    dead nodes, mixed depths and splits foreign to the table."""
+    sch = (_star_with_bare_table() if fixture == "bare_table"
+           else request.getfixturevalue(fixture)[0])
+    trees = random_trees(sch, depths=(3, 2, 2), seed=14)
+    ens = compile_ensemble(sch, trees, factor_dtype=dtype)
+    rng = np.random.default_rng(14)
+    for t in sch.tables:
+        want = eager_factor(sch, trees, t.name, dtype=dtype)
+        assert same_bits(ens.factors[t.name], want), t.name
+        rows = rng.choice(t.n_rows, size=min(6, t.n_rows), replace=False)
+        sub = sch.featmat[t.name][rows]
+        want_sub = eager_factor(sch, trees, t.name, featmat=sub, dtype=dtype)
+        assert same_bits(want_sub, want[rows]), t.name
+        assert same_bits(stack_table_factor(sch, trees, t.name, featmat=sub,
+                                            dtype=dtype), want_sub), t.name
+        masks = jax.jit(lambda fm, name=t.name: stack_table_factor(
+            sch, trees, name, featmat=fm, dtype=dtype))
+        assert same_bits(masks(sub), want_sub), t.name
+    assert same_bits(ens.leaf_values, jnp.concatenate([t.leaf for t in trees]))
+    # the feature map as the eager build made it, one scatter per feature
+    for ti, t in enumerate(sch.tables):
+        g2l = -jnp.ones((max(sch.n_features, 1),), jnp.int32)
+        for g, (tg, li) in enumerate(sch.feat_global):
+            if tg == ti:
+                g2l = g2l.at[g].set(li)
+        assert same_bits(sch.local_feature_ids[t.name], g2l), t.name
 
 
 def test_kernel_routed_scoring_matches(star, star_trees):
