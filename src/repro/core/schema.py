@@ -81,7 +81,10 @@ def _key_dict(ta: Table, tb: Table, cols: Sequence[str]):
     ka = np.stack([ta.col(c) for c in cols], axis=1)
     kb = np.stack([tb.col(c) for c in cols], axis=1)
     both = np.concatenate([ka, kb], axis=0)
-    _, inv = np.unique(both, axis=0, return_inverse=True)
+    # one key column sorts as a plain array, far faster than as rows
+    _, inv = (np.unique(both[:, 0], return_inverse=True) if len(cols) == 1
+              else np.unique(both, axis=0, return_inverse=True))
+    inv = inv.reshape(-1)
     n = int(inv.max()) + 1 if len(inv) else 0
     return (
         jnp.asarray(inv[: len(ka)], jnp.int32),
@@ -121,6 +124,9 @@ class Schema:
         # --- hypergraph + GYO -------------------------------------------------
         self._undirected_edges = self._gyo()   # list[(a, b, key_cols)] a-b adjacency
         self._tree_cache: Dict[int, JoinTree] = {}
+        # per undirected edge: ({table: key ids}, n_keys), shared by the
+        # join trees of every root
+        self._key_ids: Dict[frozenset, tuple] = {}
         for n in self.names:                   # eager: jit-safe + one-time cost
             self._build_join_tree(n)
 
@@ -236,9 +242,12 @@ class Schema:
         edges = []
         for v in reversed(order[1:]):
             p, key = parent[v]
-            cid, pid, n = _key_dict(
-                self.tables[self.index[v]], self.tables[self.index[p]], key
-            )
+            edge = frozenset((v, p))
+            if edge not in self._key_ids:
+                iv, ip, n = _key_dict(self.table(v), self.table(p), key)
+                self._key_ids[edge] = ({v: iv, p: ip}, n)
+            ids, n = self._key_ids[edge]
+            cid, pid = ids[v], ids[p]
             edges.append(
                 TreeEdge(
                     child=self.index[v], parent=self.index[p], key_cols=key,

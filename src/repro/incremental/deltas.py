@@ -182,6 +182,16 @@ class DynamicEdge:
             self.ids[table.name] = ids = np.concatenate([ids, pad])
         before = len(self.key_to_id)
         if len(slots):
-            for s, key in zip(slots, map(tuple, self._keys_at(table, slots))):
-                ids[s] = self.key_to_id.setdefault(key, len(self.key_to_id))
+            keys = self._keys_at(table, slots)
+            # each distinct key once, in order of first appearance: the
+            # ids a slot-by-slot pass would give
+            uniq, first, inv = (
+                np.unique(keys[:, 0], return_index=True, return_inverse=True)
+                if keys.shape[1] == 1 else
+                np.unique(keys, axis=0, return_index=True, return_inverse=True))
+            uid = np.empty(len(uniq), np.int32)
+            for u in np.argsort(first, kind="stable"):
+                key = tuple(uniq[u]) if uniq.ndim > 1 else (uniq[u],)
+                uid[u] = self.key_to_id.setdefault(key, len(self.key_to_id))
+            ids[slots] = uid[inv.reshape(-1)]
         return len(self.key_to_id) > before

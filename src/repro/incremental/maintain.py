@@ -18,11 +18,18 @@ incremental retraining engine (retrain.py); this module owns only the
 serving-specific state: stacked leaf-mask factors and message caches.
 
 The path-restricted refresh itself is JITTED: one compiled program per
-(root, dirty-set signature, shape fingerprint), re-emitting exactly the
-edges :func:`~repro.core.sumprod.refresh_plan` marks.  The emission
-count is bumped eagerly from the same plan, so ``QueryCounter.edges``
-accounting is identical to the eager :meth:`SumProd.refresh_messages`
-route — the IVM benchmarks' ratios are compile-cache independent.
+(root, dirty-set signature, shapes), re-emitting exactly the edges
+:func:`~repro.core.sumprod.refresh_plan` marks.  The emission count is
+bumped eagerly from the same plan, so ``QueryCounter.edges`` accounting
+is identical to the eager :meth:`SumProd.refresh_messages` route — the
+IVM benchmarks' ratios are compile-cache independent.  Every program
+here is keyed by shapes alone, so a steady delta stream compiles
+nothing: the join tree's key ids are arguments, never constants;
+messages are padded to geometric key-domain buckets (:func:`key_bucket`)
+as the append-only key dictionaries grow; and each delta's deleted and
+changed slots are padded to power-of-two lengths with out-of-range
+slots, which write nothing.  The root product and the leaf contraction
+are one program per root (``ivm.root``).
 
 The scorer duck-types the slice of :class:`CompiledEnsemble` the serving
 layer uses (``factors`` / ``leaf_values`` / ``grouped_cached`` /
@@ -45,23 +52,65 @@ the isolation is free of duplicate message emissions.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
-from typing import Dict, List, Optional, Sequence, Set, Tuple
-
 import time
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import get_registry, span
-from ..core.schema import Schema
+from ..obs import fence, get_registry, scope, span
+from ..core.schema import JoinTree, Schema
 from ..core.sumprod import QueryCounter, SumProd, refresh_plan
 from ..distributed import spmd
 from ..serving.compile import (
     CompiledEnsemble, compile_ensemble, contract_leaves, stack_table_factor)
 from .deltas import DynamicEdge, DynamicTable, TableDelta
 from .state import DynamicState, StateView
+
+
+def key_bucket(n_keys: int) -> int:
+    """Rows of a message over ``n_keys`` keys: the least of 2^k and
+    1.5 · 2^k that holds them (8 at the least).  Key dictionaries only
+    grow, so messages padded to these geometric buckets keep their
+    shape, and their programs, across most of that growth; the padded
+    keys have no rows, so their ⊕-zero entries change no score."""
+    n = max(int(n_keys), 8)
+    p = 1 << (n - 1).bit_length()
+    return 3 * p // 4 if n <= 3 * p // 4 else p
+
+
+def _slots_padded(slots: np.ndarray, capacity: int) -> np.ndarray:
+    """``slots`` as int32, padded to a power-of-two length with
+    ``capacity`` — one past the last slot, so a padded write drops."""
+    k = len(slots)
+    out = np.full((1 << (max(k, 1) - 1).bit_length(),), capacity, np.int32)
+    out[:k] = slots
+    return out
+
+
+@jax.jit
+def _write_rows(factor, gone, slots, rows):
+    """``factor`` with the rows at ``gone`` zeroed, then ``rows`` written
+    at ``slots`` (zeroing first: an insert may reuse a slot deleted in
+    the same batch).  Out-of-range slots are padding and write nothing."""
+    factor = factor.at[gone].set(0, mode="drop")
+    return factor.at[slots].set(rows.astype(factor.dtype), mode="drop")
+
+
+# slots per block of the root product: its temporaries are a few
+# (block, leaves) arrays, about 0.5 GB at 128 float32 leaves
+_ROOT_BLOCK = 1 << 18
+
+
+def _traced_tree(jt: JoinTree, child_ids, parent_ids, sizes) -> JoinTree:
+    """``jt``'s shape with the given key ids and key-domain sizes: what a
+    program sees of a join tree, so that none of its ids are constants."""
+    return JoinTree(root=jt.root, edges=tuple(
+        dataclasses.replace(e, child_ids=c, parent_ids=p, n_keys=n)
+        for e, c, p, n in zip(jt.edges, child_ids, parent_ids, sizes)))
 
 
 class MaintainedScorer:
@@ -83,7 +132,6 @@ class MaintainedScorer:
                  snapshot_retention: int = 4):
         sch = ens.schema
         self.schema = sch
-        self.source = ens
         self.trees = ens.trees
         self.leaf_values = ens.leaf_values
         self.tree0_leaves = ens.tree0_leaves
@@ -110,12 +158,11 @@ class MaintainedScorer:
             ]), self.mesh)
         self.leaf_values = spmd.replicate_put(self.leaf_values, self.mesh)
 
-        # jitted per-table delta-row mask evaluation (compile-once per
-        # (table, delta-rows) shape — the apply() hot path)
-        self._mask_fns: Dict[str, callable] = {}
         # jitted path-restricted refresh programs, keyed by
-        # (root, dirty-set, jt version, message/factor shapes)
+        # (root, dirty-set, key-domain buckets, message/factor shapes)
         self._refresh_fns: Dict[tuple, tuple] = {}
+        # per root index: the jitted root product + contraction
+        self._root_fns: Dict[int, callable] = {}
 
         # per-root cached state (created lazily on first score)
         self._msgs: Dict[str, List[jnp.ndarray]] = {}
@@ -167,7 +214,8 @@ class MaintainedScorer:
         # the state lock makes the whole batch one atomic version step:
         # a concurrent snapshot() observes either none or all of it, and
         # never a factor scatter without its data_version bump
-        with self.state.lock, span("ivm.apply", n_deltas=len(deltas)):
+        with self.state.lock, scope("ivm.apply", n_deltas=len(deltas)):
+            touched: Dict[str, Tuple[list, list]] = {}
             for ch in self.state.apply(deltas):
                 if ch.grew:
                     cur = self.factors[ch.table]
@@ -178,22 +226,28 @@ class MaintainedScorer:
                         cur,
                         jnp.zeros((cap - cur.shape[0], cur.shape[1]), cur.dtype),
                     ]), self.mesh)
-                # zero deleted slots BEFORE scattering fresh rows: an insert in
-                # this same delta may have reused a just-deleted slot
-                if len(ch.deleted):
-                    gone = jnp.asarray(ch.deleted, jnp.int32)
-                    self.factors[ch.table] = self.factors[ch.table].at[gone].set(0)
-                if len(ch.changed):
-                    self._refresh_factor_rows(ch.table, ch.changed)
                 if len(ch.changed) or len(ch.deleted):
+                    gone, changed = touched.setdefault(ch.table, ([], []))
+                    gone.append(ch.deleted)
+                    changed.append(ch.changed)
                     ti = self.schema.index[ch.table]
                     now = time.perf_counter()
                     for root in self._msgs:
                         self._dirty.setdefault(root, set()).add(ti)
                         self._stale_since.setdefault(root, now)
+            # one write per table for the whole batch, from the rows as the
+            # batch leaves them: slots dead at its end read ⊕-zero, live
+            # changed slots their fresh masks
+            for table, (gone, changed) in touched.items():
+                live = self.tables[table].live
+                gone = np.concatenate(gone)
+                changed = np.unique(np.concatenate(changed))
+                self._write_factor_rows(table, gone[~live[gone]],
+                                        changed[live[changed]])
             self._grouped.clear()
             self.data_version += 1
             self._gc_snapshots()
+            fence(self.factors)
         reg = get_registry()
         reg.counter("ivm.deltas").inc(len(deltas))
         reg.histogram("ivm.apply_ms").observe((time.perf_counter() - t0) * 1e3)
@@ -236,52 +290,46 @@ class MaintainedScorer:
             reg.histogram("ivm.refresh_lag_s").observe(time.perf_counter() - t)
         reg.gauge("ivm.staleness_s").set(self.staleness_s())
 
-    def _refresh_factor_rows(self, table: str, slots: np.ndarray):
-        """Re-evaluate the stacked leaf masks for ``slots`` and scatter
-        them into the live factor (elementwise per-row ops — identical
-        bits to a full-table recompute of the same rows)."""
+    def _write_factor_rows(self, table: str, gone: np.ndarray,
+                           slots: np.ndarray):
+        """Zero the factor rows at ``gone`` and write the stacked leaf
+        masks of ``slots``, re-evaluated from their column values
+        (elementwise per-row ops — identical bits to a full-table
+        recompute of the same rows).  Both slot lists are padded to
+        power-of-two lengths, so arbitrary delta sizes reuse at most
+        log(k) programs per table."""
         dt = self.tables[table]
         cols = self.schema.feat_cols[table]
         k = len(slots)
-        if cols:
-            rows = np.stack(
-                [dt.columns[c][slots].astype(np.float32) for c in cols], axis=1
-            )
-        else:
-            rows = np.zeros((k, 0), np.float32)
-        sl = jnp.asarray(slots, jnp.int32)
-        if table not in self._mask_fns:
-            sch, trees, dt_ = self.schema, self.trees, self.factor_dtype
-
-            def masks(featmat, table=table):
-                return stack_table_factor(sch, trees, table,
-                                          featmat=featmat, dtype=dt_)
-
-            self._mask_fns[table] = jax.jit(masks)
-        # bucket the delta size to the next power of two so arbitrary
-        # stream shapes hit at most log(k) jit compilations per table
-        k_pad = 1 << (max(k, 1) - 1).bit_length()
-        if k_pad > k:
-            rows = np.concatenate(
-                [rows, np.zeros((k_pad - k, rows.shape[1]), np.float32)]
-            )
-        frows = self._mask_fns[table](jnp.asarray(rows))
-        self.factors[table] = self.factors[table].at[sl].set(frows[:k])
+        sl = _slots_padded(slots, dt.capacity)
+        rows = np.zeros((len(sl), len(cols)), np.float32)
+        for j, c in enumerate(cols):
+            rows[:k, j] = dt.columns[c][slots]
+        with scope("ivm.factor_rows", table=table, rows=k, deleted=len(gone)):
+            frows = stack_table_factor(self.schema, self.trees, table,
+                                       featmat=jnp.asarray(rows),
+                                       dtype=self.factor_dtype)
+            self.factors[table] = _write_rows(
+                self.factors[table], _slots_padded(gone, dt.capacity), sl, frows)
 
     # ------------------------------------------------------------- scoring --
-    def _refresh_fn(self, root: str, dirty: frozenset, jt, msgs,
-                    jt_version: int, factors):
+    def _refresh_fn(self, root: str, dirty: frozenset, jt: JoinTree, msgs,
+                    factors):
         """Compiled path-restricted refresh for one (root, dirty-set,
-        shape fingerprint); returns (jitted fn, #edges it re-emits).
-        The plan is computed ONCE from :func:`refresh_plan` — the same
-        source of truth the eager route uses — so the cached program
-        re-emits exactly the edges the eager route would, and the edge
-        accounting (bumped eagerly by the caller) cannot drift.
-        ``jt``/``msgs``/``jt_version``/``factors`` are explicit so MVCC
-        snapshots pinned at an older version share this compile cache:
-        a snapshot's shapes fingerprint alongside the live scorer's."""
+        shapes); returns (jitted fn, #edges it re-emits).  The shapes are
+        the factors', the cached messages' and the key-domain buckets of
+        the messages it emits; the join tree's key ids are arguments, so
+        a new version of the same shapes reuses the program.  The plan
+        is computed ONCE from :func:`refresh_plan` — the same source of
+        truth the eager route uses — so the cached program re-emits
+        exactly the edges the eager route would, and the edge accounting
+        (bumped eagerly by the caller) cannot drift.  ``jt``/``msgs``/
+        ``factors`` are explicit so MVCC snapshots pinned at an older
+        version share this compile cache.  Each trace bumps the counter
+        ``ivm.refresh_programs``."""
+        sizes = tuple(key_bucket(e.n_keys) for e in jt.edges)
         fingerprint = (
-            root, dirty, jt_version,
+            root, dirty, sizes,
             tuple(m.shape for m in msgs),
             tuple((tn, factors[tn].shape) for tn in sorted(factors)),
         )
@@ -291,62 +339,111 @@ class MaintainedScorer:
         sem, sp = self._sem, self._sp                # node_factor never bumps
         mesh = self.mesh
         plan = refresh_plan(jt, dirty)
-        pads = [max(0, e.n_keys - msgs[i].shape[0])
-                for i, e in enumerate(jt.edges)]
+        shape = _traced_tree(jt, [None] * len(sizes), [None] * len(sizes), sizes)
 
-        def run(factors, msgs):
+        def refresh(factors, msgs, child_ids, parent_ids):
+            get_registry().counter("ivm.refresh_programs").inc()
+            tj = _traced_tree(shape, child_ids, parent_ids, sizes)
             new = list(msgs)
-            for i, e in enumerate(jt.edges):
-                if pads[i]:                          # key domain grew: ⊕-pad
-                    new[i] = jnp.concatenate(
-                        [new[i], sem.zeros((pads[i],))], axis=0
-                    )
-                if plan[i]:
-                    cf = sp.node_factor(sem, factors, jt, e.child, new)
-                    new[i] = spmd.psum_message(
-                        sem.segment_add(cf, e.child_ids, e.n_keys), mesh)
+            with scope("ivm.refresh"):
+                for i, e in enumerate(tj.edges):
+                    pad = sizes[i] - new[i].shape[0]
+                    if pad > 0:                      # key domain grew: ⊕-pad
+                        new[i] = jnp.concatenate(
+                            [new[i], sem.zeros((pad,))], axis=0)
+                    if plan[i]:
+                        cf = sp.node_factor(sem, factors, tj, e.child, new)
+                        new[i] = spmd.psum_message(
+                            sem.segment_add(cf, e.child_ids, sizes[i]), mesh)
             return new
 
-        out = (jax.jit(run), sum(plan))
+        out = (jax.jit(refresh), sum(plan))
         if len(self._refresh_fns) > 128:             # bound compile cache
             self._refresh_fns.clear()
         self._refresh_fns[fingerprint] = out
         return out
 
-    def _counts(self, group_by: str) -> jnp.ndarray:
-        """Grouped leaf counts via cached messages + jitted path refresh."""
+    def _refresh(self, root: str, dirty: frozenset, jt: JoinTree, msgs,
+                 factors) -> List[jnp.ndarray]:
+        """``msgs`` re-emitted along the root paths of ``dirty`` tables,
+        with the edge accounting and the ``ivm.refresh_ms`` timer (fenced
+        while tracing, so that it covers the device time)."""
+        t0 = time.perf_counter()
+        with span("ivm.refresh", root=root, dirty=len(dirty)):
+            run, n_emit = self._refresh_fn(root, dirty, jt, msgs, factors)
+            new = fence(run(factors, msgs,
+                            tuple(e.child_ids for e in jt.edges),
+                            tuple(e.parent_ids for e in jt.edges)))
+        if self.counter is not None:
+            self.counter.bump_edges(n_emit)
+        get_registry().histogram("ivm.refresh_ms").observe(
+            (time.perf_counter() - t0) * 1e3)
+        return new
+
+    def _score_root(self, jt: JoinTree, factors, msgs, leaf_values
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """(Σŷ, |ρ⋈J|) per slot of ``jt``'s root: the root's factor ⊗ the
+        messages of its child edges gathered by key, contracted with the
+        leaf values — one program per root, keyed by shapes, the key ids
+        its arguments.  It works through the root's slots in blocks of
+        ``_ROOT_BLOCK``, so its temporaries stay a block's whatever the
+        table's size; every slot's arithmetic is the unblocked one."""
+        ins = [i for i, e in enumerate(jt.edges) if e.parent == jt.root]
+        run = self._root_fns.get(jt.root)
+        if run is None:
+            sem, mesh, L0 = self._sem, self.mesh, self.tree0_leaves
+
+            def root_scores(factor, msgs, parent_ids, leaf_values):
+                n = factor.shape[0]
+                block = min(n, _ROOT_BLOCK)
+
+                def scores(i, out):
+                    # the last block ends at the last slot: it may
+                    # overlap the one before, and writes the same values
+                    at = jnp.minimum(i * block, n - block)
+                    f = jax.lax.dynamic_slice_in_dim(factor, at, block)
+                    for m, ids in zip(msgs, parent_ids):
+                        f = sem.mul(f, jnp.take(
+                            m, jax.lax.dynamic_slice_in_dim(ids, at, block), axis=0))
+                    # replicate before the contraction (see CompiledEnsemble)
+                    got = contract_leaves(spmd.replicate(f, mesh), leaf_values, L0)
+                    return tuple(jax.lax.dynamic_update_slice_in_dim(o, g, at, 0)
+                                 for o, g in zip(out, got))
+
+                with scope("ivm.root"):
+                    zero = jnp.zeros((n,), jnp.float32)
+                    return jax.lax.fori_loop(0, -(-n // block), scores, (zero, zero))
+
+            run = self._root_fns[jt.root] = jax.jit(root_scores)
+        root = self.schema.names[jt.root]
+        return run(factors[root], [msgs[i] for i in ins],
+                   [jt.edges[i].parent_ids for i in ins], leaf_values)
+
+    def _fresh_messages(self, group_by: str) -> Tuple[JoinTree, List[jnp.ndarray]]:
+        """The join tree and the cached messages of ``group_by``, brought
+        up to date by the jitted path refresh."""
         jt = self.state.jt(group_by)
-        sem, sp = self._sem, self._sp
         dirty = self._dirty.get(group_by)
         if group_by not in self._msgs:
             with spmd.use_data_mesh(self.mesh):
-                self._msgs[group_by] = sp.messages(sem, self.factors, jt=jt)
+                self._msgs[group_by] = self._sp.messages(
+                    self._sem, self.factors, jt=jt)
         elif dirty:
-            t0 = time.perf_counter()
-            with span("ivm.refresh", root=group_by, dirty=len(dirty)):
-                run, n_emit = self._refresh_fn(
-                    group_by, frozenset(dirty), jt, self._msgs[group_by],
-                    self.state.jt_version, self.factors)
-                self._msgs[group_by] = run(self.factors, self._msgs[group_by])
-            if self.counter is not None:
-                self.counter.bump_edges(n_emit)
-            get_registry().histogram("ivm.refresh_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
+            self._msgs[group_by] = self._refresh(
+                group_by, frozenset(dirty), jt, self._msgs[group_by],
+                self.factors)
         self._dirty[group_by] = set()
         self._last_query[group_by] = time.perf_counter()
         self._note_fresh(group_by)
-        # replicate before the serving contraction (see score_grouped)
-        return spmd.replicate(
-            sp.node_factor(sem, self.factors, jt, jt.root, self._msgs[group_by]),
-            self.mesh)
+        return jt, self._msgs[group_by]
 
     def score_grouped(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(Σŷ, |ρ⋈J|) per slot of ``group_by`` — maintained counts, same
         contraction as the compiled scorer.  Dead slots read (0, 0)."""
         if self.counter is not None:
             self.counter.bump(1)
-        return contract_leaves(self._counts(group_by), self.leaf_values,
-                               self.tree0_leaves)
+        jt, msgs = self._fresh_messages(group_by)
+        return self._score_root(jt, self.factors, msgs, self.leaf_values)
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         if group_by not in self._grouped:
@@ -463,7 +560,7 @@ class MaintainedScorer:
                               self.factor_dtype), self.mesh)
                 live = dt.live_slots()
                 if len(live):
-                    self._refresh_factor_rows(t.name, live)
+                    self._write_factor_rows(t.name, live[:0], live)
             self._msgs.clear()
             self._dirty.clear()
             self._grouped.clear()
@@ -530,7 +627,6 @@ class Snapshot:
         self.view = view
         self.data_version = data_version
         self.t_created = time.time()
-        self.jt_version = view.jt_version
         self.factors = factors
         self.leaf_values = leaf_values
         self.mesh = owner.mesh
@@ -548,32 +644,21 @@ class Snapshot:
     def n_rows(self, table: str) -> int:
         return self.view.capacities[table]
 
-    def _counts(self, group_by: str) -> jnp.ndarray:
+    def _fresh_messages(self, group_by: str):
         jt = self.view.jt(group_by)              # KeyError if not pinned
         o = self._owner
-        sem, sp = o._sem, o._sp
         with self._lock:
             msgs = self._msgs.get(group_by)
             dirty = self._dirty.get(group_by, frozenset())
             if msgs is None:
                 with spmd.use_data_mesh(self.mesh):
-                    msgs = sp.messages(sem, self.factors, jt=jt)
+                    msgs = o._sp.messages(o._sem, self.factors, jt=jt)
             elif dirty:
-                t0 = time.perf_counter()
-                with span("ivm.refresh", root=group_by, dirty=len(dirty)):
-                    run, n_emit = o._refresh_fn(
-                        group_by, dirty, jt, msgs, self.jt_version,
-                        self.factors)
-                    msgs = run(self.factors, msgs)
-                if o.counter is not None:
-                    o.counter.bump_edges(n_emit)
-                get_registry().histogram("ivm.refresh_ms").observe(
-                    (time.perf_counter() - t0) * 1e3)
+                msgs = o._refresh(group_by, dirty, jt, msgs, self.factors)
             self._msgs[group_by] = msgs
             self._dirty[group_by] = frozenset()
         o._absorb(group_by, self.data_version, msgs)
-        return spmd.replicate(
-            sp.node_factor(sem, self.factors, jt, jt.root, msgs), self.mesh)
+        return jt, msgs
 
     def score_grouped(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(Σŷ, |ρ⋈J|) per slot at this snapshot's pinned version —
@@ -581,8 +666,8 @@ class Snapshot:
         o = self._owner
         if o.counter is not None:
             o.counter.bump(1)
-        return contract_leaves(self._counts(group_by), self.leaf_values,
-                               o.tree0_leaves)
+        jt, msgs = self._fresh_messages(group_by)
+        return o._score_root(jt, self.factors, msgs, self.leaf_values)
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         with self._lock:

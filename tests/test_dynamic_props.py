@@ -184,3 +184,33 @@ def test_dynamic_state_random_stream_effective_schema_consistent():
     eff = state.effective_schema()
     for t in sch.tables:
         assert eff.table(t.name).n_rows == state.tables[t.name].n_live
+
+
+@pytest.mark.parametrize("n_cols", [1, 2])
+def test_edge_ids_follow_first_appearance(n_cols):
+    """Assigning a batch of slots gives each distinct key tuple the id a
+    slot-by-slot pass would: existing keys keep theirs, new keys take
+    the next ids in order of their first slot, repeats share one."""
+    rng = np.random.default_rng(n_cols)
+    cols = {f"k{j}": rng.integers(-3, 4, 40).astype(np.int32) for j in range(n_cols)}
+    names = tuple(cols)
+
+    def table(name, keys):
+        return DynamicTable(Table(name=name, columns=dict(keys, x=np.zeros(len(
+            keys[names[0]]), np.float32)), feature_columns=("x",)), slack=1.0)
+
+    a = table("a", {c: v[:25] for c, v in cols.items()})
+    b = table("b", {c: v[25:] for c, v in cols.items()})
+    edge = DynamicEdge(a, b, names)
+    want, ids = {}, {}
+    for t in (a, b):
+        ids[t.name] = np.zeros(t.capacity, np.int32)
+        for s in t.live_slots():
+            key = tuple(t.columns[c][s] for c in names)
+            ids[t.name][s] = want.setdefault(key, len(want))
+    assert edge.key_to_id == want
+    for t in (a, b):
+        np.testing.assert_array_equal(edge.ids[t.name], ids[t.name])
+    # a re-assignment of live slots mints nothing and moves no id
+    assert not edge.assign(a, a.live_slots()[::-1])
+    np.testing.assert_array_equal(edge.ids["a"], ids["a"])
