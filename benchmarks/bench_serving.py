@@ -82,7 +82,7 @@ def s1_one_pass_vs_leaf_loop(n_fact=2000, n_dim=64, n_trees=5, depth=3):
     c_new = QueryCounter()
     ens = compile_ensemble(sch, trees, counter=c_new)
     tot_new, cnt_new = score_grouped(ens, "fact")
-    ms_new = _timeit(lambda: ens._score_fn("fact")(ens.factors, ens.leaf_values))
+    ms_new = _timeit(lambda: ens._score_fn("fact")(*ens._score_args("fact")))
 
     # oracle: brute force over the materialized join
     J = materialize_join(sch)

@@ -279,8 +279,7 @@ def run_one_chip(args, clock: CompileClock) -> None:
     steady = time.perf_counter() - t0
     np.testing.assert_allclose(np.asarray(k_tot), np.asarray(tot), **KERNEL_TOL)
     np.testing.assert_allclose(np.asarray(k_cnt), np.asarray(cnt), **KERNEL_TOL)
-    hlo = ens_k._score_fn(group).lower(ens_k.factors, ens_k.leaf_values) \
-        .compile().as_text()
+    hlo = ens_k._score_fn(group).as_text()
     _check("tpu_custom_call" in hlo, "kernel route compiled without the kernel")
     ph.report(steady_s=f"{steady:.4f}", tpu_custom_call=True,
               max_err=_max_err(k_tot, tot))

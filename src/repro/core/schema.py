@@ -76,6 +76,14 @@ class JoinTree:
     edges: Tuple[TreeEdge, ...]   # in elimination (leaf-first) order
 
 
+def traced_tree(jt: JoinTree, child_ids, parent_ids, sizes) -> JoinTree:
+    """``jt``'s shape with the given key ids and key-domain sizes: what a
+    program sees of a join tree, so that none of its ids are constants."""
+    return JoinTree(root=jt.root, edges=tuple(
+        dataclasses.replace(e, child_ids=c, parent_ids=p, n_keys=n)
+        for e, c, p, n in zip(jt.edges, child_ids, parent_ids, sizes)))
+
+
 def _key_dict(ta: Table, tb: Table, cols: Sequence[str]):
     """Dense dictionary over the union of both tables' key tuples."""
     ka = np.stack([ta.col(c) for c in cols], axis=1)

@@ -52,7 +52,6 @@ the isolation is free of duplicate message emissions.
 """
 from __future__ import annotations
 
-import dataclasses
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -62,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import fence, get_registry, scope, span
-from ..core.schema import JoinTree, Schema
+from ..core.schema import JoinTree, Schema, traced_tree
 from ..core.sumprod import QueryCounter, SumProd, refresh_plan
 from ..distributed import spmd
 from ..serving.compile import (
@@ -103,14 +102,6 @@ def _write_rows(factor, gone, slots, rows):
 # slots per block of the root product: its temporaries are a few
 # (block, leaves) arrays, about 0.5 GB at 128 float32 leaves
 _ROOT_BLOCK = 1 << 18
-
-
-def _traced_tree(jt: JoinTree, child_ids, parent_ids, sizes) -> JoinTree:
-    """``jt``'s shape with the given key ids and key-domain sizes: what a
-    program sees of a join tree, so that none of its ids are constants."""
-    return JoinTree(root=jt.root, edges=tuple(
-        dataclasses.replace(e, child_ids=c, parent_ids=p, n_keys=n)
-        for e, c, p, n in zip(jt.edges, child_ids, parent_ids, sizes)))
 
 
 class MaintainedScorer:
@@ -339,11 +330,11 @@ class MaintainedScorer:
         sem, sp = self._sem, self._sp                # node_factor never bumps
         mesh = self.mesh
         plan = refresh_plan(jt, dirty)
-        shape = _traced_tree(jt, [None] * len(sizes), [None] * len(sizes), sizes)
+        shape = traced_tree(jt, [None] * len(sizes), [None] * len(sizes), sizes)
 
         def refresh(factors, msgs, child_ids, parent_ids):
             get_registry().counter("ivm.refresh_programs").inc()
-            tj = _traced_tree(shape, child_ids, parent_ids, sizes)
+            tj = traced_tree(shape, child_ids, parent_ids, sizes)
             new = list(msgs)
             with scope("ivm.refresh"):
                 for i, e in enumerate(tj.edges):

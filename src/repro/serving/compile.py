@@ -13,7 +13,8 @@ transpose, cast to ``factor_dtype``, is T_t's factor in a
 from ONE program keyed by shapes alone (``_factor_program``): the trees
 and the feature matrices are its arguments, so a new ensemble or a new
 dataset of the same shapes reuses it.  ONE inside-out pass grouped by
-ρ's table then yields
+ρ's table (``_scorer``: one program per structure, the join tree's key
+ids among its arguments, shared by every ensemble) then yields
 
     counts[ρ, a] = |{x ∈ ρ ⋈ J : x in leaf a}|        (all a at once)
 
@@ -39,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.schema import Schema
+from ..core.schema import JoinTree, Schema, traced_tree
 from ..core.semiring import Channels
 from ..core.sumprod import QueryCounter, SumProd
 from ..core.tree import TreeArrays, stacked_leaf_masks
@@ -132,6 +133,63 @@ def stack_table_factor(
     return _stacked_factors(schema, trees, {table: fm}, dtype)[0][table]
 
 
+# one-pass scorer programs by structure, shared by every ensemble
+_scorer_programs: Dict[tuple, object] = {}
+
+
+def _scorer(schema: Schema, jt: JoinTree, sem: Channels, tree0_leaves: int,
+            mesh, args: tuple):
+    """The one-pass scorer program grouped by ``jt``'s root, built ahead
+    of time once per structure: the join tree's skeleton (root, and each
+    edge's child, parent and key-domain size), the tables' factor shapes
+    and dtypes, the leaf counts, the semiring's type and dtype, and the
+    data mesh.  The factors, the leaf values and the join tree's key ids
+    (``args``) are its arguments, never constants, so every ensemble of
+    that structure runs it, over any dataset.  The mesh is part of the
+    key because the message emissions read it as they are traced.
+
+    ``serve.scorer_build`` scopes a build (trace, lower, compile or load
+    from the cache); each bumps the counter ``serve.scorer_programs`` and
+    adds its host milliseconds to the histogram ``serve.scorer_build_ms``.
+    Each call that finds its program built bumps ``serve.scorer_hits``."""
+    factors, vals = args[0], args[1]
+    sizes = [e.n_keys for e in jt.edges]
+    no_ids = [None] * len(sizes)
+    skeleton = traced_tree(jt, no_ids, no_ids, sizes)
+    key = (skeleton,
+           tuple((t, factors[t].shape, factors[t].dtype) for t in schema.names),
+           vals.shape, vals.dtype, tree0_leaves, type(sem), jnp.dtype(sem.dtype),
+           mesh)
+    reg = get_registry()
+    prog = _scorer_programs.get(key)
+    if prog is not None:
+        reg.counter("serve.scorer_hits").inc()
+        return prog
+    sp = SumProd(schema)
+
+    def run(factors, vals, child_ids, parent_ids):
+        tj = traced_tree(skeleton, child_ids, parent_ids, sizes)
+        msgs = sp.messages(sem, factors, jt=tj)
+        counts = sp.node_factor(sem, factors, tj, tj.root, msgs)   # (n_g, A)
+        # the rows stay sharded through the whole pass; only the
+        # two (n_g,) results are gathered back
+        with scope("serve.contract"):
+            tot, cnt = contract_leaves(counts, vals, tree0_leaves)
+        return spmd.replicate(tot, mesh), spmd.replicate(cnt, mesh)
+
+    t0 = time.perf_counter()
+    with scope("serve.scorer_build", group_by=schema.names[jt.root]), \
+            spmd.use_data_mesh(mesh):
+        prog = jax.jit(run).lower(*args).compile()
+    reg.counter("serve.scorer_programs").inc()
+    reg.histogram("serve.scorer_build_ms").observe(
+        (time.perf_counter() - t0) * 1e3)
+    if len(_scorer_programs) > 128:                   # bound compile cache
+        _scorer_programs.clear()
+    _scorer_programs[key] = prog
+    return prog
+
+
 @dataclasses.dataclass
 class CompiledEnsemble:
     """A trained ensemble lowered to single-pass relational scoring.
@@ -169,7 +227,6 @@ class CompiledEnsemble:
     mesh: Optional[object] = None          # jax.sharding.Mesh | None
 
     def __post_init__(self):
-        self._sp = SumProd(self.schema)
         self._sem = (
             KernelChannels(self.total_leaves, self.factor_dtype)
             if self.use_kernel else Channels(self.total_leaves, self.factor_dtype)
@@ -199,45 +256,32 @@ class CompiledEnsemble:
 
     # ----------------------------------------------------------- scoring --
     def _score_fn(self, group_by: str):
-        """One-pass scorer program for one grouping table, built ahead of
-        time on first use (``serve.scorer_build``: trace, lower, compile
-        or load from the cache) and kept.  Each build bumps the counter
-        ``serve.scorer_programs`` and adds its host milliseconds to the
-        histogram ``serve.scorer_build_ms``."""
+        """The one-pass scorer program for one grouping table, taken on
+        first use from the programs every ensemble of one structure
+        shares (``_scorer``) and kept."""
         if group_by not in self._score_fns:
-            sp, sem, L0 = self._sp, self._sem, self.tree0_leaves
-
-            mesh = self.mesh
-
-            def run(factors, vals):
-                counts = sp(sem, factors, group_by=group_by)   # (n_g, A)
-                # the rows stay sharded through the whole pass; only the
-                # two (n_g,) results are gathered back
-                with scope("serve.contract"):
-                    tot, cnt = contract_leaves(counts, vals, L0)
-                return (spmd.replicate(tot, mesh),
-                        spmd.replicate(cnt, mesh))
-
-            t0 = time.perf_counter()
-            with scope("serve.scorer_build", group_by=group_by):
-                self._score_fns[group_by] = jax.jit(run).lower(
-                    self.factors, self.leaf_values).compile()
-            reg = get_registry()
-            reg.counter("serve.scorer_programs").inc()
-            reg.histogram("serve.scorer_build_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
+            self._score_fns[group_by] = _scorer(
+                self.schema, self.schema.join_tree(group_by), self._sem,
+                self.tree0_leaves,
+                self.mesh if self.device_count() > 1 else None,
+                self._score_args(group_by))
         return self._score_fns[group_by]
+
+    def _score_args(self, group_by: str) -> tuple:
+        """The scorer's arguments: factors, leaf values and the key ids
+        of ``group_by``'s join tree."""
+        jt = self.schema.join_tree(group_by)
+        return (self.factors, self.leaf_values,
+                tuple(e.child_ids for e in jt.edges),
+                tuple(e.parent_ids for e in jt.edges))
 
     def score_grouped(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(Σŷ, |ρ⋈J|) per row of ``group_by`` — ONE SumProd evaluation."""
         if self.counter is not None:
             self.counter.bump(1)
-        # the build must see this ensemble's mesh — psum_message inside
-        # the pass reads the ambient context at trace time
-        with spmd.use_data_mesh(self.mesh):
-            run = self._score_fn(group_by)
-            with scope("serve.score", group_by=group_by):
-                return run(self.factors, self.leaf_values)
+        run = self._score_fn(group_by)
+        with scope("serve.score", group_by=group_by):
+            return run(*self._score_args(group_by))
 
     def grouped_cached(self, group_by: str) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Memoized full-table scores: tables are static per model version,

@@ -84,3 +84,33 @@ def test_factor_program_compiles_for_tpu_in_one_pass(one_chip):
         trees, (shape((N_ROWS, 11), jnp.float32),), views=views,
         dtype=jnp.dtype(jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_scorer_program_takes_key_ids_as_arguments_on_tpu(one_chip):
+    """The one-pass scorer at the rescore's fact size (2^20 rows, 128
+    leaf channels) compiles for a v5e with the join's key ids among its
+    parameters: no (2^20,) id array is left in the program as a constant,
+    so one program serves every dataset of these shapes."""
+    import re
+
+    from repro.core.semiring import Channels
+    from repro.relational.generators import star_schema
+    from repro.serving.compile import _scorer, _scorer_programs
+
+    def shape(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip)
+
+    sch = star_schema(seed=0, n_fact=N_ROWS, n_dim=4096)
+    jt = sch.join_tree("fact")
+    factors = {t.name: jax.ShapeDtypeStruct((t.n_rows, 128), jnp.float32,
+                                            sharding=one_chip) for t in sch.tables}
+    args = (factors, jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip),
+            tuple(shape(e.child_ids) for e in jt.edges),
+            tuple(shape(e.parent_ids) for e in jt.edges))
+    compiled = _scorer(sch, jt, Channels(128, jnp.float32), 16, None, args)
+    for key in [k for k, v in _scorer_programs.items() if v is compiled]:
+        del _scorer_programs[key]          # a described chip's program runs nowhere
+    hlo = compiled.as_text()
+    ids = rf"s32\[{N_ROWS}\]"
+    assert re.search(ids + r"[^\n]* parameter\(", hlo)
+    assert not re.search(ids + r"[^\n]* constant\(", hlo)

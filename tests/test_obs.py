@@ -214,20 +214,43 @@ def test_level_program_bytes_gauge_after_sketch_fit():
 
 
 def test_scorer_programs_counted_once_per_compiled_ensemble():
+    """One scorer program per structure, not per ensemble: a second
+    ensemble on the schema, or a schema of the same shapes over other key
+    ids, takes the program built for the first and bumps
+    ``serve.scorer_hits``; another tree-0 leaf count, factor dtype,
+    grouping table or semiring builds one more."""
+    from _trees import random_trees
     from repro.serving import compile_ensemble, score_grouped
 
-    sch = star_schema(seed=6, n_fact=40, n_dim=6)
-    trees, _ = Booster(sch, BoostConfig(n_trees=1, depth=1)).fit()
-    programs = get_registry().counter("serve.scorer_programs")
+    reg = get_registry()
+    programs = reg.counter("serve.scorer_programs")
+    hits = reg.counter("serve.scorer_hits")
+    sch = star_schema(seed=6, n_fact=37, n_dim=7)     # shapes of this test only
     fact = sch.tables[0].name
-    for _ in range(2):
-        n0 = programs.value
-        ens = compile_ensemble(sch, trees)
+    n0, h0 = programs.value, hits.value
+    for seed in (1, 2):
+        ens = compile_ensemble(sch, random_trees(sch, (2, 2), seed=seed))
         first = score_grouped(ens, fact)
-        assert programs.value == n0 + 1
         again = score_grouped(ens, fact)
-        assert programs.value == n0 + 1
         assert np.array_equal(np.asarray(first[0]), np.asarray(again[0]))
+    assert (programs.value, hits.value) == (n0 + 1, h0 + 1)
+
+    other = star_schema(seed=60, n_fact=37, n_dim=7)  # other key ids
+    assert not np.array_equal(np.asarray(other.join_tree(fact).edges[0].parent_ids),
+                              np.asarray(sch.join_tree(fact).edges[0].parent_ids))
+    score_grouped(compile_ensemble(other, random_trees(other, (2, 2), seed=3)), fact)
+    assert (programs.value, hits.value) == (n0 + 1, h0 + 2)
+
+    trees = random_trees(sch, (2, 2), seed=4)
+    variants = [
+        (compile_ensemble(sch, random_trees(sch, (1, 1, 2), seed=5)), fact),
+        (compile_ensemble(sch, trees, factor_dtype=jnp.bfloat16), fact),
+        (compile_ensemble(sch, trees), sch.tables[1].name),
+        (compile_ensemble(sch, trees, use_kernel=True), fact),
+    ]
+    for i, (ens, group) in enumerate(variants, start=2):
+        score_grouped(ens, group)
+        assert (programs.value, hits.value) == (n0 + i, h0 + 2)
 
 
 def test_factor_programs_counted_once_per_shape():

@@ -153,6 +153,37 @@ def test_factor_program_matches_eager_masks_bit_for_bit(fixture, dtype, request)
         assert same_bits(sch.local_feature_ids[t.name], g2l), t.name
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_shared_scorer_program_scores_each_ensemble_and_schema(dtype):
+    """Ensembles of one structure share one scorer program per grouping
+    table, and it holds nothing of the first it ran: A, then B, then A
+    again score A's bits twice and each its own reference; a schema of
+    the same shapes over other key ids scores to its own reference, not
+    the first's."""
+    sch = star_schema(seed=21, n_fact=53, n_dim=9)     # shapes of this test only
+    other = star_schema(seed=22, n_fact=53, n_dim=9)
+    trees = {"A": random_trees(sch, (2, 3), seed=1),
+             "B": random_trees(sch, (2, 3), seed=2),
+             "other": random_trees(other, (2, 3), seed=3)}
+    runs = [(sch, "A"), (sch, "B"), (sch, "A"), (other, "other")]
+    for group in ("fact", "dim0"):
+        out, progs = [], set()
+        for s, name in runs:
+            ens = compile_ensemble(s, trees[name], factor_dtype=dtype)
+            out.append(score_grouped(ens, group))
+            progs.add(id(ens._score_fn(group)))
+        assert len(progs) == 1
+        assert all(same_bits(a, b) for a, b in zip(out[0], out[2]))
+        for (s, name), (tot, cnt) in zip(runs, out):
+            want_tot, want_cnt = score_grouped_reference(s, trees[name], group)
+            np.testing.assert_allclose(np.asarray(tot), np.asarray(want_tot),
+                                       rtol=1e-3, atol=1e-3)
+            np.testing.assert_allclose(np.asarray(cnt), np.asarray(want_cnt),
+                                       rtol=1e-5)
+    # grouped by a dimension, the counts follow the fact table's key ids
+    assert not np.array_equal(np.asarray(out[3][1]), np.asarray(out[0][1]))
+
+
 def test_kernel_routed_scoring_matches(star, star_trees):
     sch, J, X, y = star
     trees = star_trees[:2]

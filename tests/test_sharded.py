@@ -121,6 +121,26 @@ def test_single_device_scoring_unchanged_under_mesh_context():
     assert jnp.array_equal(t1, t2) and jnp.array_equal(n1, n2)
 
 
+def test_mesh_of_one_shares_the_single_device_scorer():
+    """A size-1 mesh is the single-device program: an ensemble compiled
+    under it takes the scorer built without a mesh, builds none."""
+    from _trees import random_trees
+    from repro.obs import get_registry
+
+    programs = get_registry().counter("serve.scorer_programs")
+    sch = generators.star_schema(seed=4, n_fact=56, n_dim=12)  # shapes of this test only
+    trees = random_trees(sch, (2, 2), seed=1)
+    ens1 = compile_ensemble(sch, trees)
+    t1, n1 = score_grouped(ens1, "fact")
+    n0 = programs.value
+    with spmd.use_data_mesh(make_data_mesh(1)):
+        ens = compile_ensemble(sch, trees)
+    t2, n2 = score_grouped(ens, "fact")
+    assert programs.value == n0
+    assert ens._score_fn("fact") is ens1._score_fn("fact")
+    assert jnp.array_equal(t1, t2) and jnp.array_equal(n1, n2)
+
+
 # ------------------------------------------------------------ multi-device
 
 @multidevice
@@ -145,6 +165,30 @@ def test_sharded_grouped_scores_bit_equal(kind):
 
     assert jnp.array_equal(t1, tN) and jnp.array_equal(n1, nN)
     assert c1.edges == cN.edges and c1.count == cN.count
+
+
+@multidevice
+@pytest.mark.parametrize("kind", ["star", "chain", "snowflake"])
+def test_mesh_and_single_device_scorers_are_separate_programs(kind):
+    """The scorer program is shared by structure, and the data mesh is
+    part of it: a mesh-compiled and a single-device ensemble over the
+    same shapes build one program each, and score the same bits."""
+    from _trees import random_trees
+    from repro.obs import get_registry
+
+    programs = get_registry().counter("serve.scorer_programs")
+    sch = _schema(kind)
+    group = sch.label_table
+    trees = random_trees(sch, (3, 2), seed=11)
+    ens1 = compile_ensemble(sch, trees)
+    t1, n1 = score_grouped(ens1, group)
+    n0 = programs.value
+    with spmd.use_data_mesh(make_data_mesh()):
+        ensN = compile_ensemble(sch, trees)
+    tN, nN = score_grouped(ensN, group)
+    assert programs.value == n0 + 1
+    assert ensN._score_fn(group) is not ens1._score_fn(group)
+    assert jnp.array_equal(t1, tN) and jnp.array_equal(n1, nN)
 
 
 @multidevice
